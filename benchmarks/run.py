@@ -14,6 +14,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import use_compile_cache
+
 from . import (
     estimates_bench,
     fig1_scaling,
@@ -75,6 +77,7 @@ def main() -> None:
     unknown = [x for x in names if x not in MODULES]
     if unknown:
         p.error(f"unknown modules {unknown}; available: {list(MODULES)}")
+    use_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for name in names:

@@ -22,36 +22,43 @@ from repro.optim import sgd
 
 N_NODES, PER_NODE, ROUNDS, B_LOCAL = 16, 128, 40, 4
 
-graph = T.complete(N_NODES)  # paper cfg. A: fully-connected communication
-gain = gain_from_graph(graph)
-print(f"communication network: {graph.name};  ‖v_steady‖⁻¹ gain = {gain:.2f}\n")
 
-ds = mnist_like(N_NODES * PER_NODE + 512, seed=0)
-parts = [np.arange(i * PER_NODE, (i + 1) * PER_NODE) for i in range(N_NODES)]
-xs, ys = node_datasets(ds, parts)
-test = (ds.x[-512:], ds.y[-512:])
+def run(n_nodes=N_NODES, per_node=PER_NODE, rounds=ROUNDS, b_local=B_LOCAL):
+    """He vs gain-corrected init as one sweep; returns {label: history}."""
+    graph = T.complete(n_nodes)  # paper cfg. A: fully-connected communication
+    gain = gain_from_graph(graph)
+    print(f"communication network: {graph.name};  ‖v_steady‖⁻¹ gain = {gain:.2f}\n")
 
-loss_fn = lambda p, b: classifier_loss(mlp_forward(p, b[0]), b[1])
-opt = sgd(1e-3, momentum=0.5)
-eval_fn = make_eval_fn(loss_fn)
+    ds = mnist_like(n_nodes * per_node + 512, seed=0)
+    parts = [np.arange(i * per_node, (i + 1) * per_node) for i in range(n_nodes)]
+    xs, ys = node_datasets(ds, parts)
+    test = (ds.x[-512:], ds.y[-512:])
 
-variants = [("He et al. (uncorrected)", 1.0), ("proposed (gain-corrected)", gain)]
-states = stack_states([
-    init_fl_state(
-        jax.random.PRNGKey(0), N_NODES,
-        lambda k, g=g: init_mlp(InitConfig("he_normal", g), k), opt,
+    loss_fn = lambda p, b: classifier_loss(mlp_forward(p, b[0]), b[1])
+    opt = sgd(1e-3, momentum=0.5)
+    eval_fn = make_eval_fn(loss_fn)
+
+    variants = [("He et al. (uncorrected)", 1.0), ("proposed (gain-corrected)", gain)]
+    states = stack_states([
+        init_fl_state(
+            jax.random.PRNGKey(0), n_nodes,
+            lambda k, g=g: init_mlp(InitConfig("he_normal", g), k), opt,
+        )
+        for _, g in variants
+    ])
+    schedule = batch_index_schedule(per_node, n_nodes, 16, rounds * b_local, seed=0)
+    _, hists = run_sweep(
+        states, make_round_fn(loss_fn, opt, graph), xs, ys, schedule,
+        n_rounds=rounds, eval_every=5, eval_fn=eval_fn, eval_batch=test,
+        b_local=b_local,
     )
-    for _, g in variants
-])
-schedule = batch_index_schedule(PER_NODE, N_NODES, 16, ROUNDS * B_LOCAL, seed=0)
-_, hists = run_sweep(
-    states, make_round_fn(loss_fn, opt, graph), xs, ys, schedule,
-    n_rounds=ROUNDS, eval_every=5, eval_fn=eval_fn, eval_batch=test,
-    b_local=B_LOCAL,
-)
 
-for (label, _), hist in zip(variants, hists):
-    traj = "  ".join(f"{v:.3f}" for v in hist["test_loss"])
-    print(f"{label:28s} test loss @ rounds {hist['round']}:\n    {traj}\n")
+    for (label, _), hist in zip(variants, hists):
+        traj = "  ".join(f"{v:.3f}" for v in hist["test_loss"])
+        print(f"{label:28s} test loss @ rounds {hist['round']}:\n    {traj}\n")
+    return {label: hist for (label, _), hist in zip(variants, hists)}
 
-print("note the plateau at log(10) ≈ 2.303 without the correction (paper Fig. 1).")
+
+if __name__ == "__main__":
+    run()
+    print("note the plateau at log(10) ≈ 2.303 without the correction (paper Fig. 1).")

@@ -36,6 +36,7 @@ import numpy as np
 
 from .compress import Compression, compressed_mix, compressed_spread, init_residuals
 from .decavg import (
+    MIX_PRECISION,
     mix_pytree,
     mix_pytree_colored,
     mix_pytree_hyb,
@@ -211,9 +212,7 @@ class CommPlan:
                     self.hub_rows, self.hub_m,
                 )
             edge_w, self_w = self._sparse_round_weights(key, active, edge_live)
-            return mix_pytree_sparse(
-                params, self.src, self.dst, edge_w, self_w, n_nodes=self.n
-            )
+            return mix_pytree_sparse(params, self.src, self.dst, edge_w, self_w)
         color_w, self_w = self.color_round_weights(key, active, edge_live)
         return mix_pytree_colored(params, self.partners, color_w, self_w)
 
@@ -271,13 +270,12 @@ class CommPlan:
             x = x[:, None]
         if self.backend == "dense":
             m = self._dense_round_matrix(key, active, edge_live)
-            out = jnp.einsum("ji,jk->ik", m, x)
+            out = jnp.einsum("ji,jk->ik", m, x, precision=MIX_PRECISION)
         elif self.backend == "sparse":
             edge_w, self_w = self._sparse_round_weights(key, active, edge_live)
             contrib = edge_w[:, None] * x[self.dst]
-            out = self_w[:, None] * x + jax.ops.segment_sum(
-                contrib, self.src, num_segments=self.n
-            )
+            # self term first, then edges (decavg.mix_pytree_sparse)
+            out = (self_w[:, None] * x).at[self.src].add(contrib)
         else:
             color_w, self_w = self.color_round_weights(key, active, edge_live)
             partners = jnp.asarray(self.partners)
@@ -498,9 +496,7 @@ class CommPlan:
         edge_keep, node_act = self._round_masks_ext(key, active, edge_live)
         keep = edge_keep[self.edge_uid] & node_act[self.src] & node_act[self.dst]
         num = self.raw_edge_w * keep
-        den = self.raw_self_w + jax.ops.segment_sum(
-            num, self.dst, num_segments=self.n, indices_are_sorted=True
-        )
+        den = self.raw_self_w.at[self.dst].add(num, indices_are_sorted=True)
         return num / den[self.dst], self.raw_self_w / den
 
     def color_round_weights(

@@ -66,6 +66,12 @@ __all__ = [
 
 PyTree = Any
 
+# Mixing contractions run at full f32 precision.  A TPU's default f32 matmul
+# rounds its inputs to bf16, which would round every node's parameters to 8
+# mantissa bits each round and bury the small per-round updates the
+# trajectory is made of.  On the CPU this is the default anyway.
+MIX_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def _bcast(w: jax.Array, ndim: int) -> jax.Array:
     """Reshape a 1-D weight vector to broadcast over ``ndim - 1`` trailing dims."""
@@ -84,7 +90,9 @@ def mix_array(m: jax.Array, x: jax.Array) -> jax.Array:
     communication is the node-axis gather inherent to dense mixing (a
     reshape-to-(n, -1) here would force a full model-axis all-gather).
     """
-    out = jnp.tensordot(m, x, axes=[[1], [0]], preferred_element_type=jnp.float32)
+    out = jnp.tensordot(
+        m, x, axes=[[1], [0]], precision=MIX_PRECISION, preferred_element_type=jnp.float32
+    )
     return out.astype(x.dtype)
 
 
@@ -99,8 +107,6 @@ def mix_pytree_sparse(
     dst: jax.Array,
     edge_w: jax.Array,
     self_w: jax.Array,
-    *,
-    n_nodes: int,
 ) -> PyTree:
     """DecAvg via edge-list gather-scatter (CSR order, dst-sorted).
 
@@ -110,15 +116,19 @@ def mix_pytree_sparse(
     sum to 1) — ``commplan`` precomputes them statically or renormalises per
     round under failures.  fp32 accumulation for the same reason as
     ``mix_array``.
-    """
 
+    Each row accumulates its self term first, then its edges in CSR order,
+    written as a scatter into the self term.  XLA rewrites
+    ``a + segment_sum(b)`` into that scatter in some programs and not in
+    others, so the order is spelled out to keep every rendering of the
+    operator (eager, jitted, node-sharded) bit-identical.
+    """
     def mix_leaf(x: jax.Array) -> jax.Array:
         gathered = jnp.take(x, src, axis=0).astype(jnp.float32)
         contrib = _bcast(edge_w, x.ndim) * gathered
-        agg = jax.ops.segment_sum(
-            contrib, dst, num_segments=n_nodes, indices_are_sorted=True
+        out = (_bcast(self_w, x.ndim) * x.astype(jnp.float32)).at[dst].add(
+            contrib, indices_are_sorted=True
         )
-        out = _bcast(self_w, x.ndim) * x.astype(jnp.float32) + agg
         return out.astype(x.dtype)
 
     return jax.tree_util.tree_map(mix_leaf, params)
@@ -151,7 +161,8 @@ def mix_pytree_hyb(
             acc = acc + _bcast(slot_w[s], x.ndim) * jnp.take(xf, slot_idx[s], axis=0)
         if hub_rows is not None and hub_rows.shape[0]:
             hub_out = jnp.tensordot(
-                hub_m, xf, axes=[[1], [0]], preferred_element_type=jnp.float32
+                hub_m, xf, axes=[[1], [0]], precision=MIX_PRECISION,
+                preferred_element_type=jnp.float32,
             )
             acc = acc.at[hub_rows].set(hub_out)
         return acc.astype(x.dtype)

@@ -41,12 +41,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .commplan import CommPlan, _draw_failure_masks
-from .decavg import _bcast, mix_pytree_colored
-
-try:  # jax >= 0.6 exports shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent import
-    from jax.experimental.shard_map import shard_map as _shard_map
+from .decavg import MIX_PRECISION, _bcast, mix_pytree_colored
 
 PyTree = Any
 
@@ -381,9 +376,10 @@ class ShardedCommPlan:
         keep = t["valid"][0] & edge_keep[t["uid"][0]]
         keep = keep & active[t["gfar"][0]] & active[t["gown"][0]]
         num = t["raw_edge_w"][0] * keep
-        den = t["raw_self_w"][0] + jax.ops.segment_sum(
-            num, t["seg"][0], num_segments=self.nps + 1, indices_are_sorted=True
-        )[: self.nps]
+        # padding edges (segment nps) fall outside the scatter and drop
+        den = t["raw_self_w"][0].at[t["seg"][0]].add(
+            num, indices_are_sorted=True, mode="drop"
+        )
         den_pad = jnp.concatenate([den, jnp.ones((1,), _F32)])
         return num / den_pad[t["seg"][0]], t["raw_self_w"][0] / den
 
@@ -403,10 +399,10 @@ class ShardedCommPlan:
             x_all = self._halo_gather(x, layout, t)
             gathered = jnp.take(x_all, gat, axis=0).astype(_F32)
             contrib = _bcast(edge_w, x.ndim) * gathered
-            agg = jax.ops.segment_sum(
-                contrib, seg, num_segments=self.nps + 1, indices_are_sorted=True
-            )[: self.nps]
-            out = _bcast(self_w, x.ndim) * x.astype(_F32) + agg
+            # self term first, then edges: decavg.mix_pytree_sparse's order
+            out = (_bcast(self_w, x.ndim) * x.astype(_F32)).at[seg].add(
+                contrib, indices_are_sorted=True, mode="drop"
+            )
             return out.astype(x.dtype)
 
         return jax.tree_util.tree_map(mix_leaf, params)
@@ -433,7 +429,7 @@ class ShardedCommPlan:
                 x_full = jax.lax.all_gather(xf, self.axis, axis=0, tiled=True)
                 hub_out = jnp.tensordot(
                     t["hub_m"][0], x_full, axes=[[1], [0]],
-                    preferred_element_type=_F32,
+                    precision=MIX_PRECISION, preferred_element_type=_F32,
                 )
                 acc = acc.at[t["hub_loc"][0]].set(hub_out)
             return acc.astype(x.dtype)
@@ -455,9 +451,7 @@ class ShardedCommPlan:
             g = self.base
             keep = edge_keep[g.edge_uid] & active[g.src] & active[g.dst]
             num_g = g.raw_edge_w * keep
-            den_g = g.raw_self_w + jax.ops.segment_sum(
-                num_g, g.dst, num_segments=self.n, indices_are_sorted=True
-            )
+            den_g = g.raw_self_w.at[g.dst].add(num_g, indices_are_sorted=True)
             p = t["perm"][0]
             edge_w = jnp.where(
                 t["valid"][0], num_g[p] / den_g[t["gfar"][0]], jnp.float32(0.0)
@@ -467,10 +461,9 @@ class ShardedCommPlan:
             self_w = t["raw_self_w"][0] / den_l
         x_all = self._halo_gather(x, layout, t)
         contrib = edge_w[:, None] * x_all[t["gat"][0]]
-        agg = jax.ops.segment_sum(
-            contrib, t["seg"][0], num_segments=self.nps + 1, indices_are_sorted=True
-        )[: self.nps]
-        return self_w[:, None] * x + agg
+        return (self_w[:, None] * x).at[t["seg"][0]].add(
+            contrib, indices_are_sorted=True, mode="drop"
+        )
 
     def local_spread_min(
         self, x: jax.Array, key: jax.Array | None, t: dict
@@ -503,7 +496,8 @@ class ShardedCommPlan:
             def mix_leaf(x: jax.Array) -> jax.Array:
                 x_full = jax.lax.all_gather(x, self.axis, axis=0, tiled=True)
                 out = jnp.tensordot(
-                    block, x_full, axes=[[1], [0]], preferred_element_type=_F32
+                    block, x_full, axes=[[1], [0]],
+                    precision=MIX_PRECISION, preferred_element_type=_F32,
                 )
                 return out.astype(x.dtype)
 
@@ -511,7 +505,7 @@ class ShardedCommPlan:
         x_full = jax.lax.all_gather(payload, self.axis, axis=0, tiled=True)
         if op == "spread":
             cols = jax.lax.dynamic_slice_in_dim(m, i * self.nps, self.nps, axis=1)
-            return jnp.einsum("ji,jk->ik", cols, x_full)
+            return jnp.einsum("ji,jk->ik", cols, x_full, precision=MIX_PRECISION)
         # spread_min: surviving-neighbourhood mask rows
         keep = self.base.adjacency > 0
         if self.failures.active:
@@ -545,14 +539,14 @@ class ShardedCommPlan:
         pay_specs = self._specs_for(payload)
         tab_specs = self._specs_for(tables)
         if key is None:
-            f = _shard_map(
+            f = jax.shard_map(
                 lambda pay, t: local_fn(pay, None, t),
                 mesh=self.mesh,
                 in_specs=(pay_specs, tab_specs),
                 out_specs=pay_specs,
             )
             return f(payload, tables)
-        f = _shard_map(
+        f = jax.shard_map(
             lambda pay, k, t: local_fn(pay, k, t),
             mesh=self.mesh,
             in_specs=(pay_specs, P(), tab_specs),
@@ -682,14 +676,14 @@ class ShardedCommPlan:
         tables, tab_specs = self._colored_tables()
         pay_specs = self._specs_for(payload)
         if key is None:
-            f = _shard_map(
+            f = jax.shard_map(
                 lambda pay, t: self.local_colored(op, pay, None, t),
                 mesh=self.mesh,
                 in_specs=(pay_specs, tab_specs),
                 out_specs=pay_specs,
             )
             return f(payload, tables)
-        f = _shard_map(
+        f = jax.shard_map(
             lambda pay, k, t: self.local_colored(op, pay, k, t),
             mesh=self.mesh,
             in_specs=(pay_specs, P(), tab_specs),

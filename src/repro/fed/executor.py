@@ -54,7 +54,7 @@ from repro.core.compress import (
     init_residuals,
     seed_residual,
 )
-from repro.core.shardplan import ShardedCommPlan, _shard_map
+from repro.core.shardplan import ShardedCommPlan
 from repro.core.topology import EventStream, Graph
 from repro.obs.health import staleness_histogram
 from repro.obs.spec import BinChannel, BinSpec, Channel, MetricsSpec, Recorder
@@ -228,18 +228,22 @@ def _as_round_schedule(
 
 def _build_chunk_fn(
     round_fn,
-    xs: jax.Array,
-    ys: jax.Array,
+    n_nodes: int,
     eval_fn,
-    eval_batch,
     track_sigmas: bool,
     *,
     sweep: bool = False,
     schedule_mapped: bool = False,
     wire_fn=None,
 ):
-    """Compile-once chunk executor: (state, sched_chunk, mask_chunk) →
+    """Compile-once chunk executor: (state, sched_chunk, mask_chunk, data) →
     (state, per-round metric buffers).
+
+    ``data`` is ``(xs, ys, eval_batch)``: the per-node datasets and the test
+    batch enter as jit arguments, never as closed-over constants, so the
+    chunk compiles from their shapes alone (``tests/test_tpu_compile.py``
+    compiles it for a described TPU) and one compiled program serves every
+    dataset of that shape.
 
     The buffers are the :class:`repro.obs.Recorder`'s channels — the legacy
     train/eval/σ set (bit-identical to the hand-rolled outs this replaced)
@@ -247,60 +251,60 @@ def _build_chunk_fn(
     traced from the same ``k_mix`` the round consumes.  Returns
     ``(jitted chunk, donate, raw chunk, recorder)``.
     """
-    n_nodes = xs.shape[0]
     node_idx = jnp.arange(n_nodes)[:, None]
     rec = Recorder(
         MetricsSpec.legacy(eval_fn is not None, track_sigmas, wire=wire_fn is not None)
     )
 
-    def gather_batch(idx: jax.Array):
+    def body(data, state, per_round):
+        xs, ys, eval_batch = data
+        idx, do_eval = per_round
         # idx (n, b, bs) → ((n, b, bs, *feat), (n, b, bs))
         flat = idx.reshape(n_nodes, -1)
-        bx = xs[node_idx, flat].reshape(idx.shape + xs.shape[2:])
-        by = ys[node_idx, flat].reshape(idx.shape + ys.shape[2:])
-        return bx, by
+        batch = (
+            xs[node_idx, flat].reshape(idx.shape + xs.shape[2:]),
+            ys[node_idx, flat].reshape(idx.shape + ys.shape[2:]),
+        )
 
-    def gated_metrics(params):
-        vals = {}
-        if eval_fn is not None:
-            # Barriers keep the eval subgraph isolated from the round body so
-            # it compiles like train_loop's standalone eval_fn.  XLA still
-            # doesn't guarantee bit-identical lowering across programs: the
-            # recorded test loss can differ from the legacy path by ~1 ulp
-            # (the trajectory itself — params/PRNG/train metrics — is exact).
-            # optimization_barrier has no vmap batching rule, so the swept
-            # path goes without.
-            barrier = (lambda x: x) if sweep else jax.lax.optimization_barrier
-            with jax.named_scope("dfl_eval"):
-                per_node = barrier(eval_fn(barrier(params), eval_batch))
-            vals["test_loss"] = jnp.mean(per_node).astype(jnp.float32)
-        if track_sigmas:
-            s = sigma_metrics(params)
-            vals["sigma_ap"] = s["sigma_ap"].astype(jnp.float32)
-            vals["sigma_an"] = s["sigma_an"].astype(jnp.float32)
-        return vals
+        def gated_metrics(params):
+            vals = {}
+            if eval_fn is not None:
+                # Barriers keep the eval subgraph isolated from the round body
+                # so it compiles like train_loop's standalone eval_fn.  XLA
+                # still doesn't guarantee bit-identical lowering across
+                # programs (DESIGN.md §11).  optimization_barrier has no vmap
+                # batching rule, so the swept path goes without.
+                barrier = (lambda x: x) if sweep else jax.lax.optimization_barrier
+                with jax.named_scope("dfl_eval"):
+                    per_node = barrier(eval_fn(barrier(params), eval_batch))
+                vals["test_loss"] = jnp.mean(per_node).astype(jnp.float32)
+            if track_sigmas:
+                s = sigma_metrics(params)
+                vals["sigma_ap"] = s["sigma_ap"].astype(jnp.float32)
+                vals["sigma_an"] = s["sigma_an"].astype(jnp.float32)
+            return vals
 
-    def body(state, per_round):
-        idx, do_eval = per_round
         values = {}
         if wire_fn is not None:
             # replay the round's k_mix split before round_fn re-derives and
             # consumes it — pure bookkeeping, no PRNG stream is advanced
             _, k_mix = jax.random.split(state.rng)
             values["wire_messages"] = wire_fn(k_mix, state.round)
-        state, metrics = round_fn(state, gather_batch(idx))
+        state, metrics = round_fn(state, batch)
         values["train_loss"] = metrics["train_loss"].astype(jnp.float32)
         out = rec.step(values, gate=do_eval, gated_fn=gated_metrics, operand=state.params)
         return state, out
 
-    def chunk_inner(state, sched_chunk, mask_chunk):
-        return jax.lax.scan(body, state, (sched_chunk, mask_chunk))
+    def chunk_inner(state, sched_chunk, mask_chunk, data):
+        return jax.lax.scan(partial(body, data), state, (sched_chunk, mask_chunk))
 
     chunk = chunk_inner
     if sweep:
-        chunk = jax.vmap(chunk_inner, in_axes=(0, 0 if schedule_mapped else None, None))
+        chunk = jax.vmap(
+            chunk_inner, in_axes=(0, 0 if schedule_mapped else None, None, None)
+        )
     # Donating the carried state lets XLA reuse the ensemble's buffers across
-    # chunk calls (a no-op warning-free pass-through on CPU).  _drive_chunks
+    # chunk calls (the CPU backend does not implement donation).  _drive_chunks
     # copies the caller's state before the first call so donation never
     # invalidates it (train_loop drop-in contract).  The raw *unvmapped*
     # chunk is returned too so the fused warmups (``run_warmup_trajectory``,
@@ -308,6 +312,12 @@ def _build_chunk_fn(
     # prologues — the sweep re-vmaps the whole prologue+chunk composite.
     donate = jax.default_backend() != "cpu"
     return jax.jit(chunk, donate_argnums=(0,) if donate else ()), donate, chunk_inner, rec
+
+
+def _device_data(xs, ys, eval_batch) -> tuple:
+    """The chunk programs' ``data`` operand, placed on the default device."""
+    ev = None if eval_batch is None else jax.tree_util.tree_map(jnp.asarray, eval_batch)
+    return jnp.asarray(xs), jnp.asarray(ys), ev
 
 
 def _finish_wire(hist: dict, wire_static, row_bytes: int) -> dict:
@@ -324,7 +334,7 @@ def _drive_chunks(
     chunk_fn, state, sched_d, mask_np, cfg, *,
     round_axis: int = 0, donate: bool = False, skip: int = 0, head_outs=(),
     checkpoint: CheckpointPolicy | None = None, ckpt_meta: dict | None = None,
-    on_chunk=None,
+    on_chunk=None, operands: tuple = (),
 ):
     """Run the chunk schedule; one host sync, after the last chunk.
 
@@ -341,6 +351,8 @@ def _drive_chunks(
     chunk's device metric buffers — the streaming/telemetry hook.  Reading
     them costs only that chunk's host transfer (the same one the final
     assembly would pay); without the hook nothing syncs until the end.
+
+    ``operands`` trail every chunk call unchanged (the chunk programs' data).
     """
     if donate:
         # first chunk call would otherwise donate (delete) the caller's state
@@ -353,7 +365,7 @@ def _drive_chunks(
         sched_c = jax.tree_util.tree_map(
             lambda a: jax.lax.slice_in_dim(a, r0, r1, axis=round_axis), sched_d
         )
-        state, out = chunk_fn(state, sched_c, mask_d[r0:r1])
+        state, out = chunk_fn(state, sched_c, mask_d[r0:r1], *operands)
         outs.append(out)
         if on_chunk is not None:
             on_chunk(ci, r0, r1, out)
@@ -416,8 +428,7 @@ def run_trajectory(
     """
     cfg = TrajectoryConfig(n_rounds, eval_every, track_sigmas, chunk_size)
     sched_d = jnp.asarray(_as_round_schedule(schedule, n_rounds, b_local))
-    xs_d, ys_d = jnp.asarray(xs), jnp.asarray(ys)
-    eval_d = None if eval_batch is None else jax.tree_util.tree_map(jnp.asarray, eval_batch)
+    data = _device_data(xs, ys, eval_batch)
     eff_plan = plan if plan is not None else getattr(round_fn, "plan", None)
     wire_fn, wire_static = None, None
     if eff_plan is not None:
@@ -434,7 +445,7 @@ def run_trajectory(
         state.params, codec_bytes=comp.leaf_row_bytes if comp is not None else None
     )
     chunk_fn, donate, _, rec = _build_chunk_fn(
-        round_fn, xs_d, ys_d, eval_fn, eval_d, track_sigmas, wire_fn=wire_fn
+        round_fn, xs.shape[0], eval_fn, track_sigmas, wire_fn=wire_fn
     )
     meta_id = {
         "kind": "trajectory", "n_rounds": n_rounds, "eval_every": eval_every,
@@ -459,7 +470,7 @@ def run_trajectory(
     state, cols = _drive_chunks(
         chunk_fn, state, sched_d, mask_np, cfg, donate=donate,
         skip=skip, head_outs=head_outs, checkpoint=checkpoint, ckpt_meta=meta_id,
-        on_chunk=hook,
+        on_chunk=hook, operands=(data,),
     )
     hist = _finish_wire(rec.assemble(mask_np, cols), wire_static, row_bytes)
     return state, hist
@@ -516,8 +527,7 @@ def run_sharded_trajectory(
         raise ValueError(f"plan has {plan.n} nodes but xs carries {n_nodes}")
     cfg = TrajectoryConfig(n_rounds, eval_every, track_sigmas, 0)
     sched_d = jnp.asarray(_as_round_schedule(schedule, n_rounds, b_local))
-    xs_d, ys_d = jnp.asarray(xs), jnp.asarray(ys)
-    eval_d = None if eval_batch is None else jax.tree_util.tree_map(jnp.asarray, eval_batch)
+    xs_d, ys_d, eval_d = _device_data(xs, ys, eval_batch)
     mesh, ax, nps, n = plan.mesh, plan.axis, plan.nps, plan.n
     tables, tab_specs = plan.mix_operands()
     has_eval = eval_fn is not None
@@ -571,11 +581,13 @@ def run_sharded_trajectory(
         metrics = [jax.lax.psum(losses.sum(), ax).astype(jnp.float32) / n]
         if has_eval:
             # local eval sum under cond (no collective inside the branch),
-            # psum unconditionally: psum(NaN) = NaN keeps skip semantics
+            # psum unconditionally: psum(NaN) = NaN keeps skip semantics.
+            # The skip branch's NaN is cast to vary over the node axis like
+            # the eval branch's shard-local sum.
             local = jax.lax.cond(
                 do_eval,
                 lambda p: jnp.sum(eval_fn(p, eval_d)).astype(jnp.float32),
-                lambda p: jnp.float32(jnp.nan),
+                lambda p: jax.lax.pcast(jnp.float32(jnp.nan), ax, to="varying"),
                 params,
             )
             metrics.append(jax.lax.psum(local, ax) / n)
@@ -614,7 +626,7 @@ def run_sharded_trajectory(
     else:
         carry0 = (state.params, state.opt_state, state.rng)
         cspecs = (pspecs, ospecs, P())
-    f = _shard_map(
+    f = jax.shard_map(
         traj,
         mesh=mesh,
         in_specs=(
@@ -626,8 +638,6 @@ def run_sharded_trajectory(
             tab_specs,
         ),
         out_specs=(cspecs, tuple(P() for _ in range(n_metrics))),
-        check_rep=False,  # scalar outs are psum-replicated; the static checker
-        # can't always prove it through scan+cond on older jax
     )
     carry, metrics = jax.jit(f)(
         carry0, sched_d, jnp.asarray(mask_np), xs_d, ys_d, tables
@@ -821,8 +831,7 @@ def run_event_trajectory(
     s = np.asarray(schedule)
     n_sched_rounds = (s.shape[0] // b_local) if s.ndim == 3 else s.shape[0]
     sched_d = jnp.asarray(_as_round_schedule(s, n_sched_rounds, b_local))
-    xs_d, ys_d = jnp.asarray(xs), jnp.asarray(ys)
-    eval_d = None if eval_batch is None else jax.tree_util.tree_map(jnp.asarray, eval_batch)
+    xs_d, ys_d, eval_d = _device_data(xs, ys, eval_batch)
 
     # ---- static host realisation of the stream's metric structure --------
     env = stream.envelope
@@ -1071,8 +1080,7 @@ def run_elastic_trajectory(
     cfg = TrajectoryConfig(n_rounds, eval_every, False, chunk_size)
     mask_np = cfg.eval_mask()
     sched_np = _as_round_schedule(schedule, n_rounds, b_local)
-    xs_d, ys_d = jnp.asarray(xs), jnp.asarray(ys)
-    eval_d = None if eval_batch is None else jax.tree_util.tree_map(jnp.asarray, eval_batch)
+    xs_d, ys_d, eval_d = _device_data(xs, ys, eval_batch)
     node_idx = jnp.arange(n_nodes)[:, None]
     n_edges = plan.n_edges_env if scheduled else plan.n_edges
     if trivial_faults:
@@ -1297,32 +1305,31 @@ def run_warmup_trajectory(
     """
     cfg = TrajectoryConfig(n_rounds, eval_every, track_sigmas, chunk_size)
     sched_d = jnp.asarray(_as_round_schedule(schedule, n_rounds, b_local))
-    xs_d, ys_d = jnp.asarray(xs), jnp.asarray(ys)
-    eval_d = None if eval_batch is None else jax.tree_util.tree_map(jnp.asarray, eval_batch)
-    chunk_fn, _, chunk_raw, rec = _build_chunk_fn(
-        round_fn, xs_d, ys_d, eval_fn, eval_d, track_sigmas
-    )
+    data = _device_data(xs, ys, eval_batch)
+    chunk_fn, _, chunk_raw, rec = _build_chunk_fn(round_fn, n_nodes, eval_fn, track_sigmas)
 
     comp = getattr(round_fn, "compression", None)
 
     @jax.jit
-    def warmup_chunk(k, sched_c, mask_c):
+    def warmup_chunk(k, sched_c, mask_c, data):
         k_est, k_init = jax.random.split(k)
         gains = estimate_gains(k_est)
         state = init_fl_state(k_init, n_nodes, init_one, optimizer, gains=gains)
         state = seed_residual(state, comp)  # static scan-carry structure
-        state, out = chunk_raw(state, sched_c, mask_c)
+        state, out = chunk_raw(state, sched_c, mask_c, data)
         return state, out, gains
 
     mask_np = cfg.eval_mask()
     r0, r1 = cfg.chunks()[0]
     state, out, gains = warmup_chunk(
-        key, jax.lax.slice_in_dim(sched_d, r0, r1, axis=0), jnp.asarray(mask_np[r0:r1])
+        key, jax.lax.slice_in_dim(sched_d, r0, r1, axis=0), jnp.asarray(mask_np[r0:r1]),
+        data,
     )
     # later chunks may donate `state` — it was created inside warmup_chunk,
     # so no caller-owned buffer is ever invalidated (donate=False: no copy)
     state, cols = _drive_chunks(
-        chunk_fn, state, sched_d, mask_np, cfg, skip=1, head_outs=[out]
+        chunk_fn, state, sched_d, mask_np, cfg, skip=1, head_outs=[out],
+        operands=(data,),
     )
     hist = rec.assemble(mask_np, cols)
     return state, hist, np.asarray(gains)
@@ -1380,10 +1387,9 @@ def run_warmup_sweep(
     else:
         sched = _as_round_schedule(schedule, n_rounds, b_local)
     sched_d = jnp.asarray(sched)
-    xs_d, ys_d = jnp.asarray(xs), jnp.asarray(ys)
-    eval_d = None if eval_batch is None else jax.tree_util.tree_map(jnp.asarray, eval_batch)
+    data = _device_data(xs, ys, eval_batch)
     chunk_fn, _, chunk_inner, rec = _build_chunk_fn(
-        round_fn, xs_d, ys_d, eval_fn, eval_d, track_sigmas,
+        round_fn, n_nodes, eval_fn, track_sigmas,
         sweep=True, schedule_mapped=schedule_per_run,
     )
     has_budget = budgets is not None
@@ -1395,16 +1401,16 @@ def run_warmup_sweep(
 
     comp = getattr(round_fn, "compression", None)
 
-    def one(k, b, sched_c, mask_c):
+    def one(k, b, sched_c, mask_c, data):
         k_est, k_init = jax.random.split(k)
         gains = estimate_gains(k_est, b) if has_budget else estimate_gains(k_est)
         state = init_fl_state(k_init, n_nodes, init_one, optimizer, gains=gains)
         state = seed_residual(state, comp)  # static scan-carry structure
-        state, out = chunk_inner(state, sched_c, mask_c)
+        state, out = chunk_inner(state, sched_c, mask_c, data)
         return state, out, gains
 
     warmup_chunk = jax.jit(
-        jax.vmap(one, in_axes=(0, 0, 0 if schedule_per_run else None, None))
+        jax.vmap(one, in_axes=(0, 0, 0 if schedule_per_run else None, None, None))
     )
     mask_np = cfg.eval_mask()
     axis = 1 if schedule_per_run else 0
@@ -1414,10 +1420,11 @@ def run_warmup_sweep(
         b_arr,
         jax.lax.slice_in_dim(sched_d, r0, r1, axis=axis),
         jnp.asarray(mask_np[r0:r1]),
+        data,
     )
     states, cols = _drive_chunks(
         chunk_fn, states, sched_d, mask_np, cfg,
-        round_axis=axis, skip=1, head_outs=[out],
+        round_axis=axis, skip=1, head_outs=[out], operands=(data,),
     )
     hists = [rec.assemble(mask_np, [c[i] for c in cols]) for i in range(n_runs)]
     return states, hists, np.asarray(gains)
@@ -1459,15 +1466,14 @@ def run_sweep(
     else:
         sched = _as_round_schedule(schedule, n_rounds, b_local)
     sched_d = jnp.asarray(sched)
-    xs_d, ys_d = jnp.asarray(xs), jnp.asarray(ys)
-    eval_d = None if eval_batch is None else jax.tree_util.tree_map(jnp.asarray, eval_batch)
     chunk_fn, donate, _, rec = _build_chunk_fn(
-        round_fn, xs_d, ys_d, eval_fn, eval_d, track_sigmas,
+        round_fn, xs.shape[0], eval_fn, track_sigmas,
         sweep=True, schedule_mapped=schedule_per_run,
     )
     state, cols = _drive_chunks(
         chunk_fn, states, sched_d, cfg.eval_mask(), cfg,
         round_axis=1 if schedule_per_run else 0, donate=donate,
+        operands=(_device_data(xs, ys, eval_batch),),
     )
     mask = cfg.eval_mask()
     hists = [rec.assemble(mask, [c[i] for c in cols]) for i in range(n_runs)]

@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core.commplan import CommPlan, compile_plan
+from repro.core.decavg import MIX_PRECISION
 from repro.core.topology import EventStream, Graph
 from repro.models import transformer as tf
 from repro.obs.health import staleness_histogram
@@ -64,7 +65,7 @@ def consensus_params(node_params: PyTree, weights: jax.Array | None = None) -> P
             out = lf.mean(axis=0)
         else:
             w = weights / weights.sum()
-            out = jnp.tensordot(w, lf, axes=1)
+            out = jnp.tensordot(w, lf, axes=1, precision=MIX_PRECISION)
         return out.astype(leaf.dtype)
 
     return jax.tree_util.tree_map(avg, node_params)

@@ -33,6 +33,10 @@ from repro.launch import steps as steps_mod
 from repro.launch.mesh import make_production_mesh
 from repro.models.transformer import unit_size
 
+# the chip the 256/512-chip pod meshes stand for (a roofline.PEAKS key); the
+# host devices that back those meshes have no peaks of their own
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 # long_500k requires sub-quadratic state (DESIGN.md §4): native runners only
 LONG_CONTEXT_ARCHS = {"gemma3_4b", "jamba_1p5_large_398b", "rwkv6_3b"}
 
@@ -97,7 +101,7 @@ def run_one(
                 )
                 if hasattr(mem, k)
             }
-            full_terms = rl.terms_from_costs(cost, hlo)
+            full_terms = rl.terms_from_costs(cost, hlo, TARGET_DEVICE_KIND)
             rec["raw_terms_scan_body_once"] = full_terms.as_dict()
 
             # ---- scan-depth-corrected roofline terms ------------------
@@ -121,7 +125,7 @@ def run_one(
                     comp_t = (
                         jax.jit(step_t, in_shardings=in_t, out_shardings=out_t).lower(*args_t).compile()
                     )
-                    sub.append(rl.terms_from_costs(comp_t.cost_analysis(), comp_t.as_text()))
+                    sub.append(rl.terms_from_costs(comp_t.cost_analysis(), comp_t.as_text(), TARGET_DEVICE_KIND))
                 terms = rl.extrapolate_depth(sub[0], sub[1], n_full)
             else:
                 # train/prefill: 6-point (period × seq) fit with unrolled
@@ -153,7 +157,7 @@ def run_one(
                             .lower(*args_t)
                             .compile()
                         )
-                        points[(periods, s)] = rl.terms_from_costs(comp_t.cost_analysis(), comp_t.as_text())
+                        points[(periods, s)] = rl.terms_from_costs(comp_t.cost_analysis(), comp_t.as_text(), TARGET_DEVICE_KIND)
                 # frontend tokens scale with S in the fit; correct the target
                 # text length implicitly via seq_target evaluation
                 terms = rl.extrapolate_depth_and_seq(points, n_full, seq_target)

@@ -1,7 +1,7 @@
 """Roofline-term derivation from compiled dry-run artifacts (deliverable g).
 
-Hardware constants (TPU v5e target, per brief):
-    197 TFLOP/s bf16 / chip,  819 GB/s HBM / chip,  ~50 GB/s / ICI link.
+Hardware constants come from ``PEAKS``, keyed by ``device.device_kind``;
+a device that is not in the table is an error, never a default.
 
 Sources: ``compiled.cost_analysis()`` (per-device FLOPs / bytes — the SPMD
 module is one device's program) and the partitioned HLO text for collective
@@ -20,9 +20,34 @@ import dataclasses
 import re
 from typing import Any
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # bytes/s / chip
-ICI_BW = 50e9  # bytes/s / link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    ici_bw: float  # interconnect bytes/s per link
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of interchip interconnect per chip.  The per-link
+# figure assumes that bandwidth is split evenly over the chip's 4 links.
+PEAKS: dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8 / 4),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The peaks of ``device_kind`` (``jax.devices()[0].device_kind``)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known kinds: {sorted(PEAKS)}"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -34,9 +59,9 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all", "col
 _SHAPE_RE = re.compile(r"(f64|f32|f16|bf16|f8e4m3fn|f8e5m2|s64|u64|s32|u32|s16|u16|s8|u8|pred|c64|c128)\[([\d,]*)\]")
 
 __all__ = [
-    "PEAK_FLOPS",
-    "HBM_BW",
-    "ICI_BW",
+    "PEAKS",
+    "Peaks",
+    "peaks_for",
     "collective_bytes",
     "RooflineTerms",
     "terms_from_costs",
@@ -103,19 +128,20 @@ class RooflineTerms:
     flops: float  # per-chip
     hbm_bytes: float  # per-chip
     coll_bytes: float  # per-chip
+    device_kind: str  # the chip the terms are priced on (a ``PEAKS`` key)
     coll_breakdown: dict[str, int] | None = None
 
     @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / peaks_for(self.device_kind).flops
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / peaks_for(self.device_kind).hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.coll_bytes / ICI_BW
+        return self.coll_bytes / peaks_for(self.device_kind).ici_bw
 
     @property
     def dominant(self) -> str:
@@ -135,12 +161,14 @@ class RooflineTerms:
         }
 
 
-def terms_from_costs(cost: dict, hlo_text: str) -> RooflineTerms:
+def terms_from_costs(cost: dict, hlo_text: str, device_kind: str) -> RooflineTerms:
+    peaks_for(device_kind)  # fail here, not at the first read of a term
     cb = collective_bytes(hlo_text)
     return RooflineTerms(
         flops=float(cost.get("flops", 0.0)),
         hbm_bytes=float(cost.get("bytes accessed", 0.0)),
         coll_bytes=float(sum(cb.values())),
+        device_kind=device_kind,
         coll_breakdown=cb,
     )
 
@@ -155,6 +183,7 @@ def extrapolate_depth(a: RooflineTerms, b: RooflineTerms, n_periods: int) -> Roo
         flops=lin(a.flops, b.flops),
         hbm_bytes=lin(a.hbm_bytes, b.hbm_bytes),
         coll_bytes=lin(a.coll_bytes, b.coll_bytes),
+        device_kind=a.device_kind,
         coll_breakdown=cb,
     )
 
@@ -203,12 +232,13 @@ def extrapolate_depth_and_seq(
         alpha = _nonneg_poly_extrapolate(seqs, alpha_pts, seq_target)
         return max(0.0, alpha + n_periods * beta)
 
-    keys = next(iter(points.values())).coll_breakdown.keys()
-    cb = {k: int(fit_metric(lambda t, k=k: t.coll_breakdown[k])) for k in keys}
+    first = next(iter(points.values()))
+    cb = {k: int(fit_metric(lambda t, k=k: t.coll_breakdown[k])) for k in first.coll_breakdown}
     return RooflineTerms(
         flops=fit_metric(lambda t: t.flops),
         hbm_bytes=fit_metric(lambda t: t.hbm_bytes),
         coll_bytes=float(sum(cb.values())),
+        device_kind=first.device_kind,
         coll_breakdown=cb,
     )
 

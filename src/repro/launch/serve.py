@@ -30,6 +30,7 @@ from repro.core.initialisation import InitConfig, gain_from_graph
 from repro.data import batch_index_schedule, mnist_like, node_datasets
 from repro.fed import init_fl_state, make_eval_fn, make_router, run_serve_trajectory, serve_summary
 from repro.fed.router import ROUTER_POLICIES, poisson_query_stream
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.paper_models import classifier_loss, init_mlp, mlp_forward
 from repro.obs.export import history_rows, run_manifest, write_run_log
 from repro.optim import sgd
@@ -49,7 +50,8 @@ def build_graph(name: str, n: int, seed: int) -> T.Graph:
     raise ValueError(f"unknown topology {name!r} (choose from {TOPOLOGIES})")
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
+    """Parse the command line (``sys.argv[1:]`` by default)."""
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--nodes", type=int, default=16)
     p.add_argument("--topology", type=str, default="ring", choices=TOPOLOGIES)
@@ -77,8 +79,15 @@ def main() -> None:
         default=200,
         help="max per-query records in the run log (0 = none)",
     )
-    args = p.parse_args()
+    return p.parse_args(argv)
 
+
+def run(args: argparse.Namespace) -> tuple[dict, dict[str, np.ndarray]]:
+    """Train and serve as ``args`` (from :func:`parse_args`) describes.
+
+    Returns the printed summary and the per-query serve record
+    (``fed.serve.run_serve_trajectory``'s ``serve`` dict).
+    """
     n = args.nodes
     graph = build_graph(args.topology, n, args.seed)
     ds = mnist_like(n * args.per_node + args.test_size, seed=args.seed)
@@ -167,6 +176,12 @@ def main() -> None:
         records.append({"kind": "summary", "wall_seconds": wall, **summ})
         n_rec = write_run_log(args.telemetry, records)
         print(f"wrote {n_rec} records to {args.telemetry}")
+    return summ, serve
+
+
+def main() -> None:
+    use_compile_cache()
+    run(parse_args())
 
 
 if __name__ == "__main__":

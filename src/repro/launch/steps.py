@@ -46,11 +46,6 @@ from repro.optim import Optimizer, sgd
 from . import shardings as shard_rules
 from .mesh import n_fl_nodes, node_axis
 
-try:  # jax >= 0.5 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 PyTree = Any
 
 __all__ = ["SHAPES", "ShapeSpec", "build_train_step", "build_prefill_step", "build_decode_step", "build"]
@@ -153,7 +148,7 @@ def build_train_step(
         elif plan.backend == "ppermute":
             ax = node_ax if len(node_ax) > 1 else node_ax[0]
             mix_specs = shard_rules.commplan_in_specs(plan.backend, node_ax)
-            mix = _shard_map(
+            mix = jax.shard_map(
                 lambda p, cw, sw: mix_pytree_colored(p, plan.partners, cw, sw, axis_name=ax),
                 mesh=mesh,
                 in_specs=(node_pspecs, *mix_specs),

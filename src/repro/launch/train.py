@@ -65,6 +65,7 @@ from repro.data import (
 )
 from repro.fed import (
     CheckpointPolicy,
+    DFLState,
     init_fl_state,
     make_eval_fn,
     make_round_fn,
@@ -79,6 +80,7 @@ from repro.gossip import (
     gains_from_estimates,
     make_gain_estimator,
 )
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as TF
 from repro.obs import gossip_health, history_rows, profile_trace, run_manifest, write_run_log
 from repro.models.paper_models import classifier_loss, cnn_forward, init_cnn, init_mlp, init_vgg16, mlp_forward, vgg16_forward
@@ -108,7 +110,8 @@ TOKEN_MODELS = {
 }
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
+    """Parse and cross-check the command line (``sys.argv[1:]`` by default)."""
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument(
         "--model",
@@ -229,7 +232,7 @@ def main() -> None:
                    "boundaries instead of printing after the run (fused "
                    "executors; sets the chunk size unless --chunk-rounds is "
                    "given — no extra device syncs beyond the chunk transfer)")
-    args = p.parse_args()
+    args = p.parse_args(argv)
     if args.join_nodes > 0 or args.fault_scenario != "none":
         args.elastic = True
     if args.uncoordinated_init and args.no_gain_correction:
@@ -262,11 +265,19 @@ def main() -> None:
     if args.resume and args.uncoordinated_init and not args.async_gossip:
         p.error("--resume is not supported through the fused warmup phase; "
                 "drop --uncoordinated-init (or resume an --elastic run)")
-    token_model = args.model in TOKEN_MODELS
-    if token_model and args.legacy_loop:
+    if args.model in TOKEN_MODELS and args.legacy_loop:
         p.error("token --model archs gather from the precomputed schedule — "
                 "they run through the fused executors, not --legacy-loop "
                 "(use --arch for the host-driven token path)")
+    return args
+
+
+def run(args: argparse.Namespace) -> tuple[DFLState, dict[str, list]]:
+    """Run the training that ``args`` (from :func:`parse_args`) describes.
+
+    Returns the final ensemble state and the recorded history.
+    """
+    token_model = args.model in TOKEN_MODELS
     compress_cfg = None
     if args.compress != "none":
         sparse = args.compress in ("topk", "qtopk")
@@ -615,6 +626,12 @@ def main() -> None:
             })
         n_rec = write_run_log(args.telemetry, records)
         print(f"telemetry: {args.telemetry} ({n_rec} records)")
+    return state, hist
+
+
+def main() -> None:
+    use_compile_cache()
+    run(parse_args())
 
 
 if __name__ == "__main__":

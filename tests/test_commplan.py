@@ -116,9 +116,7 @@ def test_sparse_segment_and_hyb_renderings_agree(family, seed):
     g = FAMILIES[family](16, seed)
     plan = compile_plan(g, "sparse")
     params = _params(g.n, seed)
-    seg = mix_pytree_sparse(
-        params, plan.src, plan.dst, plan.edge_w, plan.self_w, n_nodes=plan.n
-    )
+    seg = mix_pytree_sparse(params, plan.src, plan.dst, plan.edge_w, plan.self_w)
     hyb = mix_pytree_hyb(
         params, plan.slot_idx, plan.slot_w, plan.hyb_self_w, plan.hub_rows, plan.hub_m
     )
@@ -196,10 +194,6 @@ def test_ppermute_collective_matches_dense_in_process():
     where XLA_FLAGS=--xla_force_host_platform_device_count=8)."""
     from jax.sharding import PartitionSpec as P
 
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
     from repro.core.decavg import mix_pytree_colored
 
     n = 8
@@ -213,7 +207,7 @@ def test_ppermute_collective_matches_dense_in_process():
         }
         dense = compile_plan(g, "dense").mix(params)
         specs = {"w": P("data", None, None), "b": P("data", None)}
-        f = shard_map(
+        f = jax.shard_map(
             lambda p, cw, sw: mix_pytree_colored(p, plan.partners, cw, sw, axis_name="data"),
             mesh=mesh,
             in_specs=(specs, P(None, "data"), P("data")),

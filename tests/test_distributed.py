@@ -16,10 +16,6 @@ _SCRIPT_CIRCULANT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
     from repro.core import topology as T
     from repro.core.decavg import mix_pytree, mix_pytree_circulant
     from repro.core.mixing import receive_matrix
@@ -36,7 +32,7 @@ _SCRIPT_CIRCULANT = textwrap.dedent(
     specs = {"w": P("data", None, None), "b": P("data", None)}
     with mesh:
         circ = jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda p: mix_pytree_circulant(p, offsets=(1, 2), axis_name="data"),
                 mesh=mesh, in_specs=(specs,), out_specs=specs,
             )

@@ -3,10 +3,12 @@
 The executor re-uses the exact ``round_fn`` that ``make_round_fn`` builds and
 gathers its minibatches from ``batch_index_schedule`` — the same PRNG stream
 and the same batch order as ``train_loop`` + ``node_batch_iterator``.  The
-trajectory (params, opt state, rng, train/σ metrics) must therefore be
-bit-identical.  The recorded test loss is a read-only observable computed in
-a different XLA program; it is allowed the ~1-ulp slack XLA reserves when
-lowering the same subgraph in different programs.
+trajectory (params, opt state, rng) must therefore be bit-identical.  The
+recorded per-round losses are reductions that the legacy loop and the scan
+compute in separately compiled XLA programs, which may sum in another
+order: they agree within ``METRIC_ULPS`` units in the last place
+(DESIGN.md §11).  Lanes of one vmapped sweep are not bitwise either: XLA
+may round each lane of a batched contraction differently.
 """
 import numpy as np
 import jax
@@ -30,6 +32,8 @@ from repro.models.paper_models import classifier_loss, init_mlp, mlp_forward
 from repro.optim import sgd
 
 N, PER_NODE, BS, B_LOCAL, ROUNDS = 6, 48, 8, 2, 10
+# per-round loss reductions compiled in two programs: 1-2 ulp seen on CPU
+METRIC_ULPS = 4
 
 
 @pytest.fixture(scope="module")
@@ -55,6 +59,14 @@ def _schedule(seed=0, rounds=ROUNDS):
     return batch_index_schedule(PER_NODE, N, BS, rounds * B_LOCAL, seed=seed)
 
 
+def _assert_within_ulps(a, b, max_ulps=METRIC_ULPS):
+    a = np.asarray(a, np.float32)
+    b = np.asarray(b, np.float32)
+    assert a.shape == b.shape
+    ulps = np.abs(a.view(np.int32).astype(np.int64) - b.view(np.int32).astype(np.int64))
+    assert np.all(np.sign(a) == np.sign(b)) and ulps.max(initial=0) <= max_ulps, (a, b, ulps)
+
+
 def _assert_states_bit_equal(s1, s2):
     for a, b in zip(jax.tree_util.tree_leaves(s1), jax.tree_util.tree_leaves(s2)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -78,12 +90,11 @@ def _assert_parity(leg, ex):
     (s_leg, h_leg), (s_ex, h_ex) = leg, ex
     _assert_states_bit_equal(s_leg, s_ex)
     assert h_leg["round"] == h_ex["round"]
-    # the trajectory's own metrics are computed by the same round_fn: exact
-    assert h_leg["train_loss"] == h_ex["train_loss"]
     assert h_leg["sigma_ap"] == h_ex["sigma_ap"]
     assert h_leg["sigma_an"] == h_ex["sigma_an"]
-    # test loss: separate XLA program → 1-ulp slack
-    np.testing.assert_allclose(h_leg["test_loss"], h_ex["test_loss"], rtol=2e-6)
+    # loss reductions: separately compiled programs → a few ulp
+    _assert_within_ulps(h_leg["train_loss"], h_ex["train_loss"])
+    _assert_within_ulps(h_leg["test_loss"], h_ex["test_loss"])
 
 
 def test_parity_dense_backend(setup):
@@ -171,15 +182,18 @@ def test_sweep_per_run_schedules(setup):
     rf = make_round_fn(loss_fn, opt, T.complete(N))
     state = init_fl_state(jax.random.PRNGKey(0), N, init_one, opt)
     kw = dict(n_rounds=ROUNDS, eval_every=3, schedule_per_run=True)
-    # control: same schedule for both runs → identical trajectories
+    # control: same schedule for both runs → the same trajectory, up to the
+    # per-lane rounding of the vmapped program (the sweep-vs-independent
+    # tolerance of test_sweep_matches_stacked_independent_runs)
     same = np.stack([_schedule(seed=0)] * 2)
     _, h_same = run_sweep([state, state], rf, xs, ys, same, **kw)
-    assert h_same[0]["train_loss"] == h_same[1]["train_loss"]
-    # distinct schedules → run 1 must diverge from run 0
+    np.testing.assert_allclose(h_same[0]["train_loss"], h_same[1]["train_loss"], rtol=1e-5)
+    # distinct schedules → run 1 must diverge from run 0 beyond that slack
     diff = np.stack([_schedule(seed=0), _schedule(seed=1)])
     _, h_diff = run_sweep([state, state], rf, xs, ys, diff, **kw)
-    assert h_diff[0]["train_loss"] == h_same[0]["train_loss"]  # run 0 kept schedule 0
-    assert h_diff[1]["train_loss"] != h_diff[0]["train_loss"]
+    # run 0 kept schedule 0: the same program on the same inputs, bitwise
+    assert h_diff[0]["train_loss"] == h_same[0]["train_loss"]
+    assert not np.allclose(h_diff[1]["train_loss"], h_diff[0]["train_loss"], rtol=1e-5)
 
 
 def test_no_eval_history_is_empty(setup):

@@ -1,5 +1,6 @@
 """Unit tests for the roofline derivation layer (HLO parsing + extrapolation)."""
 import numpy as np
+import pytest
 
 from repro.launch import roofline as rl
 
@@ -43,16 +44,27 @@ def test_shape_bytes_tuple_and_dtypes():
 
 
 def test_terms_and_dominant():
-    t = rl.RooflineTerms(flops=197e12, hbm_bytes=819e9 * 2, coll_bytes=50e9 * 0.5)
+    t = rl.RooflineTerms(
+        flops=197e12, hbm_bytes=819e9 * 2, coll_bytes=50e9 * 0.5, device_kind="TPU v5 lite"
+    )
     assert np.isclose(t.compute_s, 1.0)
     assert np.isclose(t.memory_s, 2.0)
     assert np.isclose(t.collective_s, 0.5)
     assert t.dominant == "memory"
 
 
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", "TPU v6 lite", ""])
+def test_unknown_device_kind_has_no_peaks(kind):
+    """A device that is not in the peaks table is an error, never v5e's numbers."""
+    with pytest.raises(ValueError, match="no published peaks"):
+        rl.peaks_for(kind)
+    with pytest.raises(ValueError, match="no published peaks"):
+        rl.terms_from_costs({"flops": 1.0}, SYNTH_HLO, kind)
+
+
 def test_depth_extrapolation_linear():
-    a = rl.RooflineTerms(10.0, 100.0, 5.0, {"all-reduce": 5, "all-gather": 0, "reduce-scatter": 0, "all-to-all": 0, "collective-permute": 0})
-    b = rl.RooflineTerms(16.0, 160.0, 8.0, {"all-reduce": 8, "all-gather": 0, "reduce-scatter": 0, "all-to-all": 0, "collective-permute": 0})
+    a = rl.RooflineTerms(10.0, 100.0, 5.0, "TPU v5 lite", {"all-reduce": 5, "all-gather": 0, "reduce-scatter": 0, "all-to-all": 0, "collective-permute": 0})
+    b = rl.RooflineTerms(16.0, 160.0, 8.0, "TPU v5 lite", {"all-reduce": 8, "all-gather": 0, "reduce-scatter": 0, "all-to-all": 0, "collective-permute": 0})
     t = rl.extrapolate_depth(a, b, n_periods=10)
     # total(P) = A + (P-1)(B-A): 10 + 9*6 = 64
     assert np.isclose(t.flops, 64.0)
@@ -65,7 +77,9 @@ def test_seq_extrapolation_recovers_polynomial():
     def cost(p, s):
         alpha = 3 + 2 * s
         beta = 7 + s + 0.001 * s * s
-        return rl.RooflineTerms(alpha + p * beta, 2 * (alpha + p * beta), 0.0, dict(cb0))
+        return rl.RooflineTerms(
+            alpha + p * beta, 2 * (alpha + p * beta), 0.0, "TPU v5 lite", dict(cb0)
+        )
 
     points = {(p, s): cost(p, s) for p in (1, 2) for s in (256, 512, 1024, 2048)}
     t = rl.extrapolate_depth_and_seq(points, n_periods=12, seq_target=32768)

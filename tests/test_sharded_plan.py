@@ -8,8 +8,9 @@ layout compilation is pure (tables must be deterministic), and the batched
 event path must replay the sequential event stream exactly.
 
 Multi-device cases run in a subprocess with 8 forced host devices (the
-``tests/test_distributed.py`` pattern) and are marked slow; the host-side
-layout and batched-event tests are tier-1.
+``tests/test_distributed.py`` pattern).  The executor and gossip-estimation
+cases are tier-1 (about 15 s each); the operator sweep over every backend,
+failure model and shard count is marked slow.
 """
 
 import os
@@ -183,14 +184,12 @@ def test_sharded_operators_bit_identical():
     assert "OPERATORS_OK" in _run(_SCRIPT_OPERATORS)
 
 
-@pytest.mark.slow
 def test_sharded_executor_parity():
     """run_sharded_trajectory: final params bit-identical to run_trajectory,
     psum-reduced metrics within float tolerance, NaN eval mask preserved."""
     assert "EXECUTOR_OK" in _run(_SCRIPT_EXECUTOR)
 
 
-@pytest.mark.slow
 def test_sharded_gossip_estimation_parity():
     """The estimation engine over a sharded plan reproduces the unsharded
     estimates bit-exactly (spread / spread_min through the halo exchange)."""
