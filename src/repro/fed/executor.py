@@ -58,6 +58,7 @@ from repro.core.shardplan import ShardedCommPlan
 from repro.core.topology import EventStream, Graph
 from repro.obs.health import staleness_histogram
 from repro.obs.spec import BinChannel, BinSpec, Channel, MetricsSpec, Recorder
+from repro.obs.trace import count, span
 from repro.obs.wirecost import (
     make_wire_fn,
     param_row_bytes,
@@ -145,14 +146,15 @@ def _save_chunk_ckpt(
 ) -> None:
     due = policy.every <= 1 or (chunk_idx + 1) % policy.every == 0
     if due or is_last or policy.kill_after == chunk_idx:
-        payload = {
-            "carry": [np.asarray(jax.device_get(l)) for l in jax.tree_util.tree_leaves(carry)],
-            "outs": [[np.asarray(c) for c in o] for o in outs],
-        }
-        save_train_state(
-            policy.dir, chunk_idx, payload,
-            meta={**meta, "chunk": chunk_idx}, keep_last=policy.keep_last,
-        )
+        with span("dfl.chunk.checkpoint", ci=chunk_idx):
+            payload = {
+                "carry": [np.asarray(jax.device_get(l)) for l in jax.tree_util.tree_leaves(carry)],
+                "outs": [[np.asarray(c) for c in o] for o in outs],
+            }
+            save_train_state(
+                policy.dir, chunk_idx, payload,
+                meta={**meta, "chunk": chunk_idx}, keep_last=policy.keep_last,
+            )
     if policy.kill_after == chunk_idx:
         os.kill(os.getpid(), signal.SIGKILL)
 
@@ -260,11 +262,12 @@ def _build_chunk_fn(
         xs, ys, eval_batch = data
         idx, do_eval = per_round
         # idx (n, b, bs) → ((n, b, bs, *feat), (n, b, bs))
-        flat = idx.reshape(n_nodes, -1)
-        batch = (
-            xs[node_idx, flat].reshape(idx.shape + xs.shape[2:]),
-            ys[node_idx, flat].reshape(idx.shape + ys.shape[2:]),
-        )
+        with jax.named_scope("dfl_batch"):
+            flat = idx.reshape(n_nodes, -1)
+            batch = (
+                xs[node_idx, flat].reshape(idx.shape + xs.shape[2:]),
+                ys[node_idx, flat].reshape(idx.shape + ys.shape[2:]),
+            )
 
         def gated_metrics(params):
             vals = {}
@@ -277,9 +280,11 @@ def _build_chunk_fn(
                 barrier = (lambda x: x) if sweep else jax.lax.optimization_barrier
                 with jax.named_scope("dfl_eval"):
                     per_node = barrier(eval_fn(barrier(params), eval_batch))
-                vals["test_loss"] = jnp.mean(per_node).astype(jnp.float32)
+                with jax.named_scope("dfl_round"):
+                    vals["test_loss"] = jnp.mean(per_node).astype(jnp.float32)
             if track_sigmas:
-                s = sigma_metrics(params)
+                with jax.named_scope("dfl_sigma"):
+                    s = sigma_metrics(params)
                 vals["sigma_ap"] = s["sigma_ap"].astype(jnp.float32)
                 vals["sigma_an"] = s["sigma_an"].astype(jnp.float32)
             return vals
@@ -288,14 +293,16 @@ def _build_chunk_fn(
         if wire_fn is not None:
             # replay the round's k_mix split before round_fn re-derives and
             # consumes it — pure bookkeeping, no PRNG stream is advanced
-            _, k_mix = jax.random.split(state.rng)
-            values["wire_messages"] = wire_fn(k_mix, state.round)
+            with jax.named_scope("dfl_wire"):
+                _, k_mix = jax.random.split(state.rng)
+                values["wire_messages"] = wire_fn(k_mix, state.round).astype(jnp.float32)
         state, metrics = round_fn(state, batch)
         values["train_loss"] = metrics["train_loss"].astype(jnp.float32)
         out = rec.step(values, gate=do_eval, gated_fn=gated_metrics, operand=state.params)
         return state, out
 
     def chunk_inner(state, sched_chunk, mask_chunk, data):
+        count("dfl.chunk_traces")
         return jax.lax.scan(partial(body, data), state, (sched_chunk, mask_chunk))
 
     chunk = chunk_inner
@@ -362,21 +369,26 @@ def _drive_chunks(
     chunks = cfg.chunks()
     for ci in range(skip, len(chunks)):
         r0, r1 = chunks[ci]
-        sched_c = jax.tree_util.tree_map(
-            lambda a: jax.lax.slice_in_dim(a, r0, r1, axis=round_axis), sched_d
-        )
-        state, out = chunk_fn(state, sched_c, mask_d[r0:r1], *operands)
-        outs.append(out)
-        if on_chunk is not None:
-            on_chunk(ci, r0, r1, out)
-        if checkpoint is not None:
-            _save_chunk_ckpt(
-                checkpoint, ci, ci == len(chunks) - 1, state, outs, ckpt_meta or {}
-            )
-    n_cols = len(outs[0])
-    cols = [
-        np.concatenate([np.asarray(o[i]) for o in outs], axis=-1) for i in range(n_cols)
-    ]
+        with span("dfl.chunk", ci=ci, r0=r0, r1=r1):
+            with span("dfl.chunk.slice"):
+                sched_c = jax.tree_util.tree_map(
+                    lambda a: jax.lax.slice_in_dim(a, r0, r1, axis=round_axis), sched_d
+                )
+                mask_c = mask_d[r0:r1]
+            with span("dfl.chunk.dispatch"):
+                state, out = chunk_fn(state, sched_c, mask_c, *operands)
+            outs.append(out)
+            if on_chunk is not None:
+                on_chunk(ci, r0, r1, out)
+            if checkpoint is not None:
+                _save_chunk_ckpt(
+                    checkpoint, ci, ci == len(chunks) - 1, state, outs, ckpt_meta or {}
+                )
+    with span("dfl.assemble"):
+        cols = [
+            np.concatenate([np.asarray(o[i]) for o in outs], axis=-1)
+            for i in range(len(outs[0]))
+        ]
     return state, cols
 
 
@@ -427,52 +439,56 @@ def run_trajectory(
     the chunk's own host transfer, paid early instead of at the end.
     """
     cfg = TrajectoryConfig(n_rounds, eval_every, track_sigmas, chunk_size)
-    sched_d = jnp.asarray(_as_round_schedule(schedule, n_rounds, b_local))
-    data = _device_data(xs, ys, eval_batch)
-    eff_plan = plan if plan is not None else getattr(round_fn, "plan", None)
-    wire_fn, wire_static = None, None
-    if eff_plan is not None:
-        if eff_plan.failures.active:
-            wire_fn = make_wire_fn(eff_plan)
-        else:
-            wire_static = static_wire_messages(eff_plan, n_rounds)
-    # compressed round_fns (make_round_fn(compression=...)) carry their codec:
-    # the mirror seeds into the carry before the scan (static structure) and
-    # wire bytes price at the codec's encoding, not the raw itemsize
-    comp: Compression | None = getattr(round_fn, "compression", None)
-    state = seed_residual(state, comp)
-    row_bytes = param_row_bytes(
-        state.params, codec_bytes=comp.leaf_row_bytes if comp is not None else None
-    )
-    chunk_fn, donate, _, rec = _build_chunk_fn(
-        round_fn, xs.shape[0], eval_fn, track_sigmas, wire_fn=wire_fn
-    )
-    meta_id = {
-        "kind": "trajectory", "n_rounds": n_rounds, "eval_every": eval_every,
-        "track_sigmas": track_sigmas, "chunk_size": cfg.chunk_size,
-        "compressed": comp is not None,
-    }
-    mask_np = cfg.eval_mask()
-    hook = None
-    if on_chunk is not None:
-        def hook(ci, r0, r1, out):
-            del ci
-            h = rec.assemble(mask_np[r0:r1], [np.asarray(c) for c in out])
-            h["round"] = [r + r0 for r in h["round"]]
-            on_chunk(r0, r1, _finish_wire(h, wire_static, row_bytes))
-    skip, head_outs = 0, ()
-    if resume_from is not None:
-        resumed = _load_resume(resume_from, meta_id)
-        if resumed is not None:
-            payload, skip = resumed
-            state = _restore_carry(state, payload)
-            head_outs = [tuple(np.asarray(c) for c in o) for o in payload["outs"]]
-    state, cols = _drive_chunks(
-        chunk_fn, state, sched_d, mask_np, cfg, donate=donate,
-        skip=skip, head_outs=head_outs, checkpoint=checkpoint, ckpt_meta=meta_id,
-        on_chunk=hook, operands=(data,),
-    )
-    hist = _finish_wire(rec.assemble(mask_np, cols), wire_static, row_bytes)
+    count("dfl.calls")
+    with span("dfl.trajectory", n_rounds=n_rounds, chunks=len(cfg.chunks())):
+        sched_d = jnp.asarray(_as_round_schedule(schedule, n_rounds, b_local))
+        data = _device_data(xs, ys, eval_batch)
+        eff_plan = plan if plan is not None else getattr(round_fn, "plan", None)
+        wire_fn, wire_static = None, None
+        if eff_plan is not None:
+            if eff_plan.failures.active:
+                wire_fn = make_wire_fn(eff_plan)
+            else:
+                wire_static = static_wire_messages(eff_plan, n_rounds)
+        # compressed round_fns (make_round_fn(compression=...)) carry their codec:
+        # the mirror seeds into the carry before the scan (static structure) and
+        # wire bytes price at the codec's encoding, not the raw itemsize
+        comp: Compression | None = getattr(round_fn, "compression", None)
+        state = seed_residual(state, comp)
+        row_bytes = param_row_bytes(
+            state.params, codec_bytes=comp.leaf_row_bytes if comp is not None else None
+        )
+        chunk_fn, donate, _, rec = _build_chunk_fn(
+            round_fn, xs.shape[0], eval_fn, track_sigmas, wire_fn=wire_fn
+        )
+        meta_id = {
+            "kind": "trajectory", "n_rounds": n_rounds, "eval_every": eval_every,
+            "track_sigmas": track_sigmas, "chunk_size": cfg.chunk_size,
+            "compressed": comp is not None,
+        }
+        mask_np = cfg.eval_mask()
+        hook = None
+        if on_chunk is not None:
+            def hook(ci, r0, r1, out):
+                del ci
+                with span("dfl.chunk.fetch"):
+                    h = rec.assemble(mask_np[r0:r1], [np.asarray(c) for c in out])
+                    h["round"] = [r + r0 for r in h["round"]]
+                    h = _finish_wire(h, wire_static, row_bytes)
+                on_chunk(r0, r1, h)
+        skip, head_outs = 0, ()
+        if resume_from is not None:
+            resumed = _load_resume(resume_from, meta_id)
+            if resumed is not None:
+                payload, skip = resumed
+                state = _restore_carry(state, payload)
+                head_outs = [tuple(np.asarray(c) for c in o) for o in payload["outs"]]
+        state, cols = _drive_chunks(
+            chunk_fn, state, sched_d, mask_np, cfg, donate=donate,
+            skip=skip, head_outs=head_outs, checkpoint=checkpoint, ckpt_meta=meta_id,
+            on_chunk=hook, operands=(data,),
+        )
+        hist = _finish_wire(rec.assemble(mask_np, cols), wire_static, row_bytes)
     return state, hist
 
 
@@ -526,141 +542,155 @@ def run_sharded_trajectory(
     if plan.n != n_nodes:
         raise ValueError(f"plan has {plan.n} nodes but xs carries {n_nodes}")
     cfg = TrajectoryConfig(n_rounds, eval_every, track_sigmas, 0)
-    sched_d = jnp.asarray(_as_round_schedule(schedule, n_rounds, b_local))
-    xs_d, ys_d, eval_d = _device_data(xs, ys, eval_batch)
-    mesh, ax, nps, n = plan.mesh, plan.axis, plan.nps, plan.n
-    tables, tab_specs = plan.mix_operands()
-    has_eval = eval_fn is not None
-    failures_active = plan.failures.active
-    mask_np = cfg.eval_mask()
-    node_idx = jnp.arange(nps)[:, None]
-    comp = compression if (compression is not None and compression.active) else None
+    count("dfl.calls")
+    with span("dfl.trajectory", n_rounds=n_rounds, chunks=1):
+        sched_d = jnp.asarray(_as_round_schedule(schedule, n_rounds, b_local))
+        xs_d, ys_d, eval_d = _device_data(xs, ys, eval_batch)
+        mesh, ax, nps, n = plan.mesh, plan.axis, plan.nps, plan.n
+        tables, tab_specs = plan.mix_operands()
+        has_eval = eval_fn is not None
+        failures_active = plan.failures.active
+        mask_np = cfg.eval_mask()
+        node_idx = jnp.arange(nps)[:, None]
+        comp = compression if (compression is not None and compression.active) else None
 
-    def sharded_sigmas(params):
-        # σ_ap: per-node moments are shard-local; σ_an needs cross-shard
-        # per-parameter moments — two psum phases (sum, then centred sum)
-        leaves = [
-            l.reshape(l.shape[0], -1).astype(jnp.float32)
-            for l in jax.tree_util.tree_leaves(params)
-        ]
-        d_total = sum(l.shape[1] for l in leaves)
-        mean_n = sum(l.sum(axis=1) for l in leaves) / d_total
-        var_n = sum(((l - mean_n[:, None]) ** 2).sum(axis=1) for l in leaves) / d_total
-        ap = jax.lax.psum(jnp.sqrt(var_n).sum(), ax) / n
-        an_sum = jnp.float32(0.0)
-        for l in leaves:
-            m = jax.lax.psum(l.sum(axis=0), ax) / n
-            v = jax.lax.psum(((l - m[None, :]) ** 2).sum(axis=0), ax) / n
-            an_sum = an_sum + jnp.sqrt(v).sum()
-        return ap.astype(jnp.float32), (an_sum / d_total).astype(jnp.float32)
+        def sharded_sigmas(params):
+            # σ_ap: per-node moments are shard-local; σ_an needs cross-shard
+            # per-parameter moments — two psum phases (sum, then centred sum)
+            leaves = [
+                l.reshape(l.shape[0], -1).astype(jnp.float32)
+                for l in jax.tree_util.tree_leaves(params)
+            ]
+            d_total = sum(l.shape[1] for l in leaves)
+            mean_n = sum(l.sum(axis=1) for l in leaves) / d_total
+            var_n = sum(((l - mean_n[:, None]) ** 2).sum(axis=1) for l in leaves) / d_total
+            ap = jax.lax.psum(jnp.sqrt(var_n).sum(), ax) / n
+            an_sum = jnp.float32(0.0)
+            for l in leaves:
+                m = jax.lax.psum(l.sum(axis=0), ax) / n
+                v = jax.lax.psum(((l - m[None, :]) ** 2).sum(axis=0), ax) / n
+                an_sum = an_sum + jnp.sqrt(v).sum()
+            return ap.astype(jnp.float32), (an_sum / d_total).astype(jnp.float32)
 
-    def body(carry, per_round, xs_l, ys_l, t):
+        def body(carry, per_round, xs_l, ys_l, t):
+            if comp is not None:
+                params, opt_state, rng, mirror = carry
+            else:
+                (params, opt_state, rng), mirror = carry, None
+            idx, do_eval = per_round  # idx: (nps, b, bs) local slice of the schedule
+            with jax.named_scope("dfl_round"):
+                rng, k_mix = jax.random.split(rng)
+            with jax.named_scope("dfl_batch"):
+                flat = idx.reshape(nps, -1)
+                bx = xs_l[node_idx, flat].reshape(idx.shape + xs_l.shape[2:])
+                by = ys_l[node_idx, flat].reshape(idx.shape + ys_l.shape[2:])
+            with jax.named_scope("dfl_local"):
+                params, opt_state, losses = jax.vmap(partial(_local_steps, loss_fn, optimizer))(
+                    params, opt_state, (bx, by)
+                )
+            key = k_mix if failures_active else None
+            with jax.named_scope("dfl_mix"):
+                if comp is not None:
+                    # delta-form compressed halo mix: the mirror is shard-local (a
+                    # per-node-row transform), only h' rides the halo exchange
+                    params, mirror = compressed_mix_with(
+                        lambda q: plan.local_mix_any(q, key, t), params, mirror, comp
+                    )
+                else:
+                    params = plan.local_mix_any(params, key, t)
+            if reinit_opt:  # Algorithm 1 line 15
+                with jax.named_scope("dfl_reinit"):
+                    opt_state = jax.vmap(optimizer.init)(params)
+            with jax.named_scope("dfl_round"):
+                metrics = [jax.lax.psum(losses.sum(), ax).astype(jnp.float32) / n]
+            if has_eval:
+                # local eval sum under cond (no collective inside the branch),
+                # psum unconditionally: psum(NaN) = NaN keeps skip semantics.
+                # The skip branch's NaN is cast to vary over the node axis like
+                # the eval branch's shard-local sum.
+                with jax.named_scope("dfl_eval"):
+                    local = jax.lax.cond(
+                        do_eval,
+                        lambda p: jnp.sum(eval_fn(p, eval_d)).astype(jnp.float32),
+                        lambda p: jax.lax.pcast(jnp.float32(jnp.nan), ax, to="varying"),
+                        params,
+                    )
+                    metrics.append(jax.lax.psum(local, ax) / n)
+            if track_sigmas:
+                nan = jnp.float32(jnp.nan)
+                with jax.named_scope("dfl_sigma"):
+                    ap, an = sharded_sigmas(params)
+                    metrics += [jnp.where(do_eval, ap, nan), jnp.where(do_eval, an, nan)]
+            new_carry = (
+                (params, opt_state, rng, mirror)
+                if comp is not None
+                else (params, opt_state, rng)
+            )
+            return new_carry, tuple(metrics)
+
+        def traj(carry, sched, mask, xs_l, ys_l, t):
+            count("dfl.chunk_traces")
+
+            def step(c, pr):
+                return body(c, pr, xs_l, ys_l, t)
+
+            return jax.lax.scan(step, carry, (sched, mask))
+
+        pspecs = jax.tree_util.tree_map(
+            lambda l: P(ax, *([None] * (l.ndim - 1))), state.params
+        )
+        ospecs = jax.tree_util.tree_map(
+            lambda l: P(ax, *([None] * (l.ndim - 1))), state.opt_state
+        )
+        data_spec = lambda a: P(ax, *([None] * (a.ndim - 1)))  # noqa: E731
+        n_metrics = 1 + int(has_eval) + 2 * int(track_sigmas)
+        if comp is not None:
+            carry0 = (
+                state.params, state.opt_state, state.rng,
+                state.residual if state.residual is not None
+                else init_residuals(state.params),
+            )
+            cspecs = (pspecs, ospecs, P(), pspecs)
+        else:
+            carry0 = (state.params, state.opt_state, state.rng)
+            cspecs = (pspecs, ospecs, P())
+        f = jax.shard_map(
+            traj,
+            mesh=mesh,
+            in_specs=(
+                cspecs,
+                P(None, ax, None, None),
+                P(),
+                data_spec(xs_d),
+                data_spec(ys_d),
+                tab_specs,
+            ),
+            out_specs=(cspecs, tuple(P() for _ in range(n_metrics))),
+        )
+        with span("dfl.chunk.dispatch"):
+            carry, metrics = jax.jit(f)(
+                carry0, sched_d, jnp.asarray(mask_np), xs_d, ys_d, tables
+            )
         if comp is not None:
             params, opt_state, rng, mirror = carry
         else:
             (params, opt_state, rng), mirror = carry, None
-        idx, do_eval = per_round  # idx: (nps, b, bs) local slice of the schedule
-        rng, k_mix = jax.random.split(rng)
-        flat = idx.reshape(nps, -1)
-        bx = xs_l[node_idx, flat].reshape(idx.shape + xs_l.shape[2:])
-        by = ys_l[node_idx, flat].reshape(idx.shape + ys_l.shape[2:])
-        params, opt_state, losses = jax.vmap(partial(_local_steps, loss_fn, optimizer))(
-            params, opt_state, (bx, by)
+        with span("dfl.assemble"):
+            cols = [np.asarray(m) for m in metrics]
+        # halo wire cost is a plan static (the cross-shard row set never changes
+        # round to round), so the channels are host-side constants — no buffer
+        rec = Recorder(MetricsSpec.legacy(has_eval, track_sigmas))
+        hist = rec.assemble(
+            mask_np, cols,
+            constants=sharded_wire_per_round(
+                plan, state.params,
+                codec_bytes=comp.leaf_row_bytes if comp is not None else None,
+            ),
         )
-        key = k_mix if failures_active else None
-        if comp is not None:
-            # delta-form compressed halo mix: the mirror is shard-local (a
-            # per-node-row transform), only h' rides the halo exchange
-            params, mirror = compressed_mix_with(
-                lambda q: plan.local_mix_any(q, key, t), params, mirror, comp
-            )
-        else:
-            params = plan.local_mix_any(params, key, t)
-        if reinit_opt:  # Algorithm 1 line 15
-            opt_state = jax.vmap(optimizer.init)(params)
-        metrics = [jax.lax.psum(losses.sum(), ax).astype(jnp.float32) / n]
-        if has_eval:
-            # local eval sum under cond (no collective inside the branch),
-            # psum unconditionally: psum(NaN) = NaN keeps skip semantics.
-            # The skip branch's NaN is cast to vary over the node axis like
-            # the eval branch's shard-local sum.
-            local = jax.lax.cond(
-                do_eval,
-                lambda p: jnp.sum(eval_fn(p, eval_d)).astype(jnp.float32),
-                lambda p: jax.lax.pcast(jnp.float32(jnp.nan), ax, to="varying"),
-                params,
-            )
-            metrics.append(jax.lax.psum(local, ax) / n)
-        if track_sigmas:
-            nan = jnp.float32(jnp.nan)
-            ap, an = sharded_sigmas(params)
-            metrics += [jnp.where(do_eval, ap, nan), jnp.where(do_eval, an, nan)]
-        new_carry = (
-            (params, opt_state, rng, mirror)
-            if comp is not None
-            else (params, opt_state, rng)
+        final = DFLState(
+            params=params, opt_state=opt_state,
+            round=state.round + jnp.int32(n_rounds), rng=rng, residual=mirror,
         )
-        return new_carry, tuple(metrics)
-
-    def traj(carry, sched, mask, xs_l, ys_l, t):
-        def step(c, pr):
-            return body(c, pr, xs_l, ys_l, t)
-
-        return jax.lax.scan(step, carry, (sched, mask))
-
-    pspecs = jax.tree_util.tree_map(
-        lambda l: P(ax, *([None] * (l.ndim - 1))), state.params
-    )
-    ospecs = jax.tree_util.tree_map(
-        lambda l: P(ax, *([None] * (l.ndim - 1))), state.opt_state
-    )
-    data_spec = lambda a: P(ax, *([None] * (a.ndim - 1)))  # noqa: E731
-    n_metrics = 1 + int(has_eval) + 2 * int(track_sigmas)
-    if comp is not None:
-        carry0 = (
-            state.params, state.opt_state, state.rng,
-            state.residual if state.residual is not None
-            else init_residuals(state.params),
-        )
-        cspecs = (pspecs, ospecs, P(), pspecs)
-    else:
-        carry0 = (state.params, state.opt_state, state.rng)
-        cspecs = (pspecs, ospecs, P())
-    f = jax.shard_map(
-        traj,
-        mesh=mesh,
-        in_specs=(
-            cspecs,
-            P(None, ax, None, None),
-            P(),
-            data_spec(xs_d),
-            data_spec(ys_d),
-            tab_specs,
-        ),
-        out_specs=(cspecs, tuple(P() for _ in range(n_metrics))),
-    )
-    carry, metrics = jax.jit(f)(
-        carry0, sched_d, jnp.asarray(mask_np), xs_d, ys_d, tables
-    )
-    if comp is not None:
-        params, opt_state, rng, mirror = carry
-    else:
-        (params, opt_state, rng), mirror = carry, None
-    cols = [np.asarray(m) for m in metrics]
-    # halo wire cost is a plan static (the cross-shard row set never changes
-    # round to round), so the channels are host-side constants — no buffer
-    rec = Recorder(MetricsSpec.legacy(has_eval, track_sigmas))
-    hist = rec.assemble(
-        mask_np, cols,
-        constants=sharded_wire_per_round(
-            plan, state.params,
-            codec_bytes=comp.leaf_row_bytes if comp is not None else None,
-        ),
-    )
-    final = DFLState(
-        params=params, opt_state=opt_state,
-        round=state.round + jnp.int32(n_rounds), rng=rng, residual=mirror,
-    )
     return final, hist
 
 
@@ -1073,194 +1103,200 @@ def run_elastic_trajectory(
     if membership.inits.any() and init_one is None:
         raise ValueError("membership has joining nodes: init_one(key, gain) is required")
 
-    scheduled = isinstance(plan, PlanSchedule)
-    failures_active = plan.failures.active
-    comp = compression if (compression is not None and compression.active) else None
-    has_inits = bool(membership.inits.any())
     cfg = TrajectoryConfig(n_rounds, eval_every, False, chunk_size)
-    mask_np = cfg.eval_mask()
-    sched_np = _as_round_schedule(schedule, n_rounds, b_local)
-    xs_d, ys_d, eval_d = _device_data(xs, ys, eval_batch)
-    node_idx = jnp.arange(n_nodes)[:, None]
-    n_edges = plan.n_edges_env if scheduled else plan.n_edges
-    if trivial_faults:
-        node_up = np.ones((n_rounds, n_nodes), bool)
-        edge_up = np.ones((n_rounds, max(n_edges, 1)), bool)
-    else:
-        node_up, edge_up = faults.node_up, faults.edge_up
+    count("dfl.calls")
+    with span("dfl.trajectory", n_rounds=n_rounds, chunks=len(cfg.chunks())):
+        scheduled = isinstance(plan, PlanSchedule)
+        failures_active = plan.failures.active
+        comp = compression if (compression is not None and compression.active) else None
+        has_inits = bool(membership.inits.any())
+        mask_np = cfg.eval_mask()
+        sched_np = _as_round_schedule(schedule, n_rounds, b_local)
+        xs_d, ys_d, eval_d = _device_data(xs, ys, eval_batch)
+        node_idx = jnp.arange(n_nodes)[:, None]
+        n_edges = plan.n_edges_env if scheduled else plan.n_edges
+        if trivial_faults:
+            node_up = np.ones((n_rounds, n_nodes), bool)
+            edge_up = np.ones((n_rounds, max(n_edges, 1)), bool)
+        else:
+            node_up, edge_up = faults.node_up, faults.edge_up
 
-    # aux PRNG streams fork off state.rng without consuming from it: the
-    # training stream (per-round k_mix splits) stays the static executors'
-    k_fresh, k_init = jax.random.split(jax.random.fold_in(state.rng, 0x5EED))
-    sketches0 = jax.random.exponential(
-        jax.random.fold_in(k_fresh, n_rounds), (n_nodes, n_sketches)
-    )
-
-    # wire accountant: same per-round key, membership and fault masks the
-    # mix consumes, so the count is exactly the delivered-edge set (§17)
-    wire_fn = make_wire_fn(plan)
-    channels = [Channel("train_loss")]
-    if eval_fn is not None:
-        channels.append(Channel("test_loss", gated=True))
-    channels.append(Channel("n_active", ints=True))
-    if wire_fn is not None:
-        channels.append(Channel("wire_messages", ints=True))
-    rec = Recorder(MetricsSpec(tuple(channels)))
-
-    def per_node_where(cond, new, old):
-        return jax.tree_util.tree_map(
-            lambda a, b: jnp.where(cond.reshape((-1,) + (1,) * (a.ndim - 1)), a, b),
-            new, old,
+        # aux PRNG streams fork off state.rng without consuming from it: the
+        # training stream (per-round k_mix splits) stays the static executors'
+        k_fresh, k_init = jax.random.split(jax.random.fold_in(state.rng, 0x5EED))
+        sketches0 = jax.random.exponential(
+            jax.random.fold_in(k_fresh, n_rounds), (n_nodes, n_sketches)
         )
 
-    def gather_batch(idx):
-        flat = idx.reshape(n_nodes, -1)
-        bx = xs_d[node_idx, flat].reshape(idx.shape + xs_d.shape[2:])
-        by = ys_d[node_idx, flat].reshape(idx.shape + ys_d.shape[2:])
-        return bx, by
+        # wire accountant: same per-round key, membership and fault masks the
+        # mix consumes, so the count is exactly the delivered-edge set (§17)
+        wire_fn = make_wire_fn(plan)
+        channels = [Channel("train_loss")]
+        if eval_fn is not None:
+            channels.append(Channel("test_loss", gated=True))
+        channels.append(Channel("n_active", ints=True))
+        if wire_fn is not None:
+            channels.append(Channel("wire_messages", ints=True))
+        rec = Recorder(MetricsSpec(tuple(channels)))
 
-    def body(carry, per_round):
+        def per_node_where(cond, new, old):
+            return jax.tree_util.tree_map(
+                lambda a, b: jnp.where(cond.reshape((-1,) + (1,) * (a.ndim - 1)), a, b),
+                new, old,
+            )
+
+        def gather_batch(idx):
+            flat = idx.reshape(n_nodes, -1)
+            bx = xs_d[node_idx, flat].reshape(idx.shape + xs_d.shape[2:])
+            by = ys_d[node_idx, flat].reshape(idx.shape + ys_d.shape[2:])
+            return bx, by
+
+        def body(carry, per_round):
+            if comp is not None:
+                params, opt_state, rng, sketches, mirror = carry
+            else:
+                (params, opt_state, rng, sketches), mirror = carry, None
+            idx, tr_m, gs_m, jn, ini, nup, eup, r, do_eval = per_round
+            tr_eff = tr_m & nup
+            gs_eff = gs_m & nup
+            rng, k_mix = jax.random.split(rng)
+            key = k_mix if failures_active else None
+
+            # 1. joiners whose warmup just completed initialise uncoordinated,
+            # with the size-only gain √n̂ from their own carried sketches
+            # (traced only when the schedule has inits at all — host knowledge)
+            def do_init(po):
+                p, o = po
+                gains = jnp.sqrt(jnp.maximum((n_sketches - 1) / jnp.maximum(
+                    sketches.sum(axis=1), jnp.float32(1e-30)), 1.0))
+                kr = jax.random.fold_in(k_init, r)
+                keys = jax.vmap(lambda i: jax.random.fold_in(kr, i))(jnp.arange(n_nodes))
+                p = per_node_where(ini, jax.vmap(init_one)(keys, gains), p)
+                o = per_node_where(ini, jax.vmap(optimizer.init)(p), o)
+                return p, o
+
+            if has_inits:
+                params, opt_state = jax.lax.cond(
+                    ini.any(), do_init, lambda po: po, (params, opt_state)
+                )
+
+            # 2. local phase at the full envelope; non-members are frozen
+            bx, by = gather_batch(idx)
+            new_p, new_o, losses = jax.vmap(partial(_local_steps, loss_fn, optimizer))(
+                params, opt_state, (bx, by)
+            )
+            params = per_node_where(tr_eff, new_p, params)
+            opt_state = per_node_where(tr_eff, new_o, opt_state)
+
+            # 3. sketch transport: arrivals redraw, the gossip-active population
+            # min-exchanges over the same per-round failure draws as the mix
+            fresh = jax.random.exponential(
+                jax.random.fold_in(k_fresh, r), (n_nodes, n_sketches)
+            )
+            sketches = jnp.where(jn[:, None], fresh, sketches)
+            if scheduled:
+                sketches = plan.spread_min(sketches, r, key, active=gs_eff, edge_live=eup)
+            else:
+                sketches = plan.spread_min(sketches, key, active=gs_eff, edge_live=eup)
+            if comp is not None:
+                # only live trainers transmitted → only their mirrors advance
+                params, mirror = compressed_mix(
+                    plan, params, mirror, key, compression=comp,
+                    round_index=r if scheduled else None,
+                    active=tr_eff, edge_live=eup, update_mask=tr_eff,
+                )
+            elif scheduled:
+                params = plan.mix(params, r, key, active=tr_eff, edge_live=eup)
+            else:
+                params = plan.mix(params, key, active=tr_eff, edge_live=eup)
+            if reinit_opt:  # Algorithm 1 line 15, members only
+                opt_state = per_node_where(
+                    tr_eff, jax.vmap(optimizer.init)(params), opt_state
+                )
+
+            # 4. metrics over the live training population
+            n_act = tr_eff.sum().astype(jnp.float32)
+            safe = jnp.maximum(n_act, 1.0)
+            values = {
+                "train_loss": ((losses * tr_eff).sum() / safe).astype(jnp.float32),
+                "n_active": n_act,
+            }
+            if wire_fn is not None:
+                values["wire_messages"] = wire_fn(key, r, active=tr_eff, edge_live=eup)
+
+            def gated_metrics(p):
+                return {
+                    "test_loss": ((eval_fn(p, eval_d) * tr_eff).sum() / safe).astype(jnp.float32)
+                }
+
+            out = rec.step(values, gate=do_eval, gated_fn=gated_metrics, operand=params)
+            new_carry = (params, opt_state, rng, sketches)
+            return (new_carry + (mirror,) if comp is not None else new_carry), out
+
+        def chunk_inner(carry, sched_chunk, mask_chunk):
+            count("dfl.chunk_traces")
+
+            def step(c, inp):
+                sc, do_eval = inp
+                return body(c, (*sc, do_eval))
+
+            return jax.lax.scan(step, carry, (sched_chunk, mask_chunk))
+
+        chunk_fn = jax.jit(chunk_inner)
+        sched_tuple = (
+            jnp.asarray(sched_np),
+            jnp.asarray(membership.active),
+            jnp.asarray(membership.gossip),
+            jnp.asarray(membership.joins),
+            jnp.asarray(membership.inits),
+            jnp.asarray(node_up),
+            jnp.asarray(edge_up),
+            jnp.arange(n_rounds, dtype=jnp.int32),
+        )
+        state = seed_residual(state, comp)
+        carry = (state.params, state.opt_state, state.rng, sketches0)
+        if comp is not None:
+            carry = carry + (state.residual,)
+        meta_id = {
+            "kind": "elastic", "n_rounds": n_rounds, "eval_every": eval_every,
+            "chunk_size": cfg.chunk_size, "n_sketches": n_sketches,
+            "compressed": comp is not None,
+        }
+        row_bytes = param_row_bytes(
+            state.params, codec_bytes=comp.leaf_row_bytes if comp is not None else None
+        )
+        hook = None
+        if on_chunk is not None:
+            def hook(ci, r0, r1, out):
+                del ci
+                with span("dfl.chunk.fetch"):
+                    h = rec.assemble(mask_np[r0:r1], [np.asarray(c) for c in out])
+                    h["round"] = [r + r0 for r in h["round"]]
+                    h = _finish_wire(h, None, row_bytes)
+                on_chunk(r0, r1, h)
+        skip, head_outs = 0, ()
+        if resume_from is not None:
+            resumed = _load_resume(resume_from, meta_id)
+            if resumed is not None:
+                payload, skip = resumed
+                carry = _restore_carry(carry, payload)
+                head_outs = [tuple(np.asarray(c) for c in o) for o in payload["outs"]]
+        carry, cols = _drive_chunks(
+            chunk_fn, carry, sched_tuple, mask_np, cfg,
+            skip=skip, head_outs=head_outs, checkpoint=checkpoint, ckpt_meta=meta_id,
+            on_chunk=hook,
+        )
         if comp is not None:
             params, opt_state, rng, sketches, mirror = carry
         else:
             (params, opt_state, rng, sketches), mirror = carry, None
-        idx, tr_m, gs_m, jn, ini, nup, eup, r, do_eval = per_round
-        tr_eff = tr_m & nup
-        gs_eff = gs_m & nup
-        rng, k_mix = jax.random.split(rng)
-        key = k_mix if failures_active else None
-
-        # 1. joiners whose warmup just completed initialise uncoordinated,
-        # with the size-only gain √n̂ from their own carried sketches
-        # (traced only when the schedule has inits at all — host knowledge)
-        def do_init(po):
-            p, o = po
-            gains = jnp.sqrt(jnp.maximum((n_sketches - 1) / jnp.maximum(
-                sketches.sum(axis=1), jnp.float32(1e-30)), 1.0))
-            kr = jax.random.fold_in(k_init, r)
-            keys = jax.vmap(lambda i: jax.random.fold_in(kr, i))(jnp.arange(n_nodes))
-            p = per_node_where(ini, jax.vmap(init_one)(keys, gains), p)
-            o = per_node_where(ini, jax.vmap(optimizer.init)(p), o)
-            return p, o
-
-        if has_inits:
-            params, opt_state = jax.lax.cond(
-                ini.any(), do_init, lambda po: po, (params, opt_state)
-            )
-
-        # 2. local phase at the full envelope; non-members are frozen
-        bx, by = gather_batch(idx)
-        new_p, new_o, losses = jax.vmap(partial(_local_steps, loss_fn, optimizer))(
-            params, opt_state, (bx, by)
+        hist = _finish_wire(rec.assemble(mask_np, cols), None, row_bytes)
+        final = DFLState(
+            params=params, opt_state=opt_state,
+            round=state.round + jnp.int32(n_rounds), rng=rng,
+            residual=mirror,
         )
-        params = per_node_where(tr_eff, new_p, params)
-        opt_state = per_node_where(tr_eff, new_o, opt_state)
-
-        # 3. sketch transport: arrivals redraw, the gossip-active population
-        # min-exchanges over the same per-round failure draws as the mix
-        fresh = jax.random.exponential(
-            jax.random.fold_in(k_fresh, r), (n_nodes, n_sketches)
-        )
-        sketches = jnp.where(jn[:, None], fresh, sketches)
-        if scheduled:
-            sketches = plan.spread_min(sketches, r, key, active=gs_eff, edge_live=eup)
-        else:
-            sketches = plan.spread_min(sketches, key, active=gs_eff, edge_live=eup)
-        if comp is not None:
-            # only live trainers transmitted → only their mirrors advance
-            params, mirror = compressed_mix(
-                plan, params, mirror, key, compression=comp,
-                round_index=r if scheduled else None,
-                active=tr_eff, edge_live=eup, update_mask=tr_eff,
-            )
-        elif scheduled:
-            params = plan.mix(params, r, key, active=tr_eff, edge_live=eup)
-        else:
-            params = plan.mix(params, key, active=tr_eff, edge_live=eup)
-        if reinit_opt:  # Algorithm 1 line 15, members only
-            opt_state = per_node_where(
-                tr_eff, jax.vmap(optimizer.init)(params), opt_state
-            )
-
-        # 4. metrics over the live training population
-        n_act = tr_eff.sum().astype(jnp.float32)
-        safe = jnp.maximum(n_act, 1.0)
-        values = {
-            "train_loss": ((losses * tr_eff).sum() / safe).astype(jnp.float32),
-            "n_active": n_act,
-        }
-        if wire_fn is not None:
-            values["wire_messages"] = wire_fn(key, r, active=tr_eff, edge_live=eup)
-
-        def gated_metrics(p):
-            return {
-                "test_loss": ((eval_fn(p, eval_d) * tr_eff).sum() / safe).astype(jnp.float32)
-            }
-
-        out = rec.step(values, gate=do_eval, gated_fn=gated_metrics, operand=params)
-        new_carry = (params, opt_state, rng, sketches)
-        return (new_carry + (mirror,) if comp is not None else new_carry), out
-
-    def chunk_inner(carry, sched_chunk, mask_chunk):
-        def step(c, inp):
-            sc, do_eval = inp
-            return body(c, (*sc, do_eval))
-
-        return jax.lax.scan(step, carry, (sched_chunk, mask_chunk))
-
-    chunk_fn = jax.jit(chunk_inner)
-    sched_tuple = (
-        jnp.asarray(sched_np),
-        jnp.asarray(membership.active),
-        jnp.asarray(membership.gossip),
-        jnp.asarray(membership.joins),
-        jnp.asarray(membership.inits),
-        jnp.asarray(node_up),
-        jnp.asarray(edge_up),
-        jnp.arange(n_rounds, dtype=jnp.int32),
-    )
-    state = seed_residual(state, comp)
-    carry = (state.params, state.opt_state, state.rng, sketches0)
-    if comp is not None:
-        carry = carry + (state.residual,)
-    meta_id = {
-        "kind": "elastic", "n_rounds": n_rounds, "eval_every": eval_every,
-        "chunk_size": cfg.chunk_size, "n_sketches": n_sketches,
-        "compressed": comp is not None,
-    }
-    row_bytes = param_row_bytes(
-        state.params, codec_bytes=comp.leaf_row_bytes if comp is not None else None
-    )
-    hook = None
-    if on_chunk is not None:
-        def hook(ci, r0, r1, out):
-            del ci
-            h = rec.assemble(mask_np[r0:r1], [np.asarray(c) for c in out])
-            h["round"] = [r + r0 for r in h["round"]]
-            on_chunk(r0, r1, _finish_wire(h, None, row_bytes))
-    skip, head_outs = 0, ()
-    if resume_from is not None:
-        resumed = _load_resume(resume_from, meta_id)
-        if resumed is not None:
-            payload, skip = resumed
-            carry = _restore_carry(carry, payload)
-            head_outs = [tuple(np.asarray(c) for c in o) for o in payload["outs"]]
-    carry, cols = _drive_chunks(
-        chunk_fn, carry, sched_tuple, mask_np, cfg,
-        skip=skip, head_outs=head_outs, checkpoint=checkpoint, ckpt_meta=meta_id,
-        on_chunk=hook,
-    )
-    if comp is not None:
-        params, opt_state, rng, sketches, mirror = carry
-    else:
-        (params, opt_state, rng, sketches), mirror = carry, None
-    hist = _finish_wire(rec.assemble(mask_np, cols), None, row_bytes)
-    final = DFLState(
-        params=params, opt_state=opt_state,
-        round=state.round + jnp.int32(n_rounds), rng=rng,
-        residual=mirror,
-    )
-    n_hat = (n_sketches - 1) / np.maximum(np.asarray(sketches).sum(axis=1), 1e-30)
+        n_hat = (n_sketches - 1) / np.maximum(np.asarray(sketches).sum(axis=1), 1e-30)
     return final, hist, {"n_hat": n_hat}
 
 

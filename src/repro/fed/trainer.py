@@ -148,7 +148,8 @@ def make_round_fn(
     comp = compression if (compression is not None and compression.active) else None
 
     def round_fn(state: DFLState, node_batches: Any) -> tuple[DFLState, dict]:
-        rng, k_mix = jax.random.split(state.rng)
+        with jax.named_scope("dfl_round"):
+            rng, k_mix = jax.random.split(state.rng)
 
         with jax.named_scope("dfl_local"):
             params, opt_state, losses = jax.vmap(
@@ -171,13 +172,16 @@ def make_round_fn(
                 else:
                     params = plan.mix(params, key=key)
             if reinit_opt:  # Algorithm 1 line 15
-                opt_state = jax.vmap(optimizer.init)(params)
+                with jax.named_scope("dfl_reinit"):
+                    opt_state = jax.vmap(optimizer.init)(params)
 
-        new_state = DFLState(
-            params=params, opt_state=opt_state, round=state.round + 1, rng=rng,
-            residual=residual,
-        )
-        return new_state, {"train_loss": losses.mean(), "train_loss_per_node": losses}
+        with jax.named_scope("dfl_round"):
+            new_state = DFLState(
+                params=params, opt_state=opt_state, round=state.round + 1, rng=rng,
+                residual=residual,
+            )
+            train_loss = losses.mean()
+        return new_state, {"train_loss": train_loss, "train_loss_per_node": losses}
 
     # the *effective* plan (overrides applied) — the executor's wire-cost
     # accountant reads it to count exactly the edges this round_fn mixes over;

@@ -225,8 +225,8 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
                    "DESIGN.md §17)")
     p.add_argument("--profile-trace", type=str, default=None,
                    help="capture a jax.profiler trace of the run into this "
-                   "directory (named_scope phases: dfl_local / dfl_mix / "
-                   "dfl_eval / halo_exchange)")
+                   "directory (host spans dfl.trajectory / dfl.chunk.* / "
+                   "dfl.assemble; device scopes dfl_* of repro.obs.trace)")
     p.add_argument("--log-every", type=int, default=0,
                    help="stream recorded metrics every N rounds at chunk "
                    "boundaries instead of printing after the run (fused "

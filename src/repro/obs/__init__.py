@@ -6,7 +6,13 @@ One observability layer, four writers: ``run_trajectory``,
 the :class:`MetricsSpec`/:class:`Recorder` abstraction (bit-identical to the
 hand-rolled outs they replace), report bytes-on-the-wire via the
 :mod:`~repro.obs.wirecost` accountant, and export host-side JSONL run logs
-through :mod:`~repro.obs.export`.
+through :mod:`~repro.obs.export`.  :mod:`~repro.obs.trace` names the
+executors' host spans (``dfl.trajectory``, ``dfl.chunk`` and its slice,
+dispatch, fetch and checkpoint, ``dfl.assemble``), the round body's device
+scopes (``dfl_round``, ``dfl_batch``, ``dfl_local``, ``dfl_mix``,
+``dfl_reinit``, ``dfl_wire``, ``dfl_eval``, ``dfl_sigma``) and the
+``dfl.calls``/``dfl.chunk_traces`` counters; a profiler trace records them
+on one clock.
 """
 
 from .export import (
@@ -19,8 +25,9 @@ from .export import (
     validate_run_log,
     write_run_log,
 )
-from .health import consensus_distance, gossip_health, mass_drift_trace, staleness_histogram
+from .health import gossip_health, mass_drift_trace, staleness_histogram
 from .spec import BinChannel, BinSpec, Channel, MetricsSpec, Recorder
+from .trace import COUNTERS, SCOPES, SPANS, count, counts, span
 from .wirecost import (
     make_wire_fn,
     param_row_bytes,
@@ -29,13 +36,17 @@ from .wirecost import (
 )
 
 __all__ = [
+    "COUNTERS",
     "SCHEMA_VERSION",
+    "SCOPES",
+    "SPANS",
     "BinChannel",
     "BinSpec",
     "Channel",
     "MetricsSpec",
     "Recorder",
-    "consensus_distance",
+    "count",
+    "counts",
     "git_rev",
     "gossip_health",
     "history_rows",
@@ -46,6 +57,7 @@ __all__ = [
     "read_run_log",
     "run_manifest",
     "sharded_wire_per_round",
+    "span",
     "staleness_histogram",
     "static_wire_messages",
     "validate_run_log",

@@ -8,8 +8,8 @@ Everything is sanitised to strict JSON — NaN/Inf become null, numpy scalars
 become Python numbers — so any downstream reader parses it.
 
 :func:`profile_trace` is the opt-in ``jax.profiler`` capture used by
-``launch/train.py --profile-trace DIR``; the executors' ``named_scope``
-phases (local step / mix / eval / halo) show up inside the trace.
+``launch/train.py --profile-trace DIR``; the executors' host spans and the
+round body's device scopes (:mod:`repro.obs.trace`) show up inside it.
 """
 
 from __future__ import annotations
@@ -166,8 +166,14 @@ def validate_run_log(records: list[dict] | str | Path) -> list[str]:
 def profile_trace(trace_dir: str | Path | None) -> Iterator[None]:
     """Capture a ``jax.profiler`` trace into ``trace_dir`` (no-op if falsy).
 
-    The executors' ``named_scope`` phases — ``dfl_local``, ``dfl_mix``,
-    ``dfl_eval``, ``halo_exchange`` — annotate the captured timeline.
+    On the host plane, the executors' spans (``repro.obs.trace.SPANS``):
+    ``dfl.trajectory`` per call, ``dfl.chunk`` per chunk with its
+    ``dfl.chunk.slice``, ``dfl.chunk.dispatch``, ``dfl.chunk.fetch`` and
+    ``dfl.chunk.checkpoint``, then ``dfl.assemble``.  On the device, each op
+    carries the round body's scope (``repro.obs.trace.SCOPES``):
+    ``dfl_round``, ``dfl_batch``, ``dfl_local``, ``dfl_mix`` (with
+    ``halo_exchange`` under it when sharded), ``dfl_reinit``, ``dfl_wire``,
+    ``dfl_eval``, ``dfl_sigma``.
     """
     if not trace_dir:
         yield

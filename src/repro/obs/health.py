@@ -1,9 +1,7 @@
 """Gossip-health channels (DESIGN.md §17) riding ``gossip.diagnostics``.
 
-Four measurements, all JSON-able:
+Three measurements, all JSON-able:
 
-* :func:`consensus_distance` — mean per-node L2 distance to the fleet
-  average, the quantity whose contraction the spectral gap predicts.
 * :func:`mass_drift_trace` — per-round |Σs − Σs₀|/Σs₀ of a spread payload;
   ``spread`` is column-stochastic so any drift is pure fp32 error, and this
   curve is the canary for a broken mask/renormalisation path.
@@ -22,27 +20,10 @@ import numpy as np
 from repro.gossip.diagnostics import convergence_report
 
 __all__ = [
-    "consensus_distance",
     "gossip_health",
     "mass_drift_trace",
     "staleness_histogram",
 ]
-
-
-def consensus_distance(params) -> jax.Array:
-    """Mean over nodes of ‖wᵢ − w̄‖₂ across the whole flattened model.
-
-    ``params`` is any pytree whose leaves carry a leading node axis.
-    Traceable — usable inside a scanned round body as a gated channel.
-    """
-    leaves = jax.tree_util.tree_leaves(params)
-    n = leaves[0].shape[0]
-    sq = jnp.zeros((n,), jnp.float32)
-    for leaf in leaves:
-        flat = leaf.reshape(n, -1).astype(jnp.float32)
-        dev = flat - flat.mean(axis=0, keepdims=True)
-        sq = sq + jnp.sum(dev * dev, axis=1)
-    return jnp.sqrt(sq).mean()
 
 
 def mass_drift_trace(plan, rounds: int, key=None) -> np.ndarray:

@@ -9,6 +9,11 @@ Recorder parity suite); here we cover what telemetry *adds*.
 """
 
 import json
+import os
+import re
+import subprocess
+import sys
+import textwrap
 
 import numpy as np
 import jax
@@ -28,7 +33,6 @@ from repro.obs import (
     Channel,
     MetricsSpec,
     Recorder,
-    consensus_distance,
     history_rows,
     make_wire_fn,
     param_row_bytes,
@@ -293,18 +297,191 @@ def test_trajectory_on_chunk_streams_history(setup):
 # --------------------------------------------------------- health channels
 
 
-def test_consensus_distance_hand_counted():
-    params = {"w": jnp.asarray([[0.0], [2.0]], jnp.float32)}
-    # mean over the two nodes of |w_i − 1| = 1
-    assert float(consensus_distance(params)) == 1.0
-    same = {"w": jnp.ones((4, 7), jnp.float32)}
-    assert float(consensus_distance(same)) == 0.0
-
-
 def test_staleness_histogram_edges():
     h = staleness_histogram(np.array([1.0, 0.0, 3.0, 0.0]), horizon=8.0)
     assert h["counts"] == [1.0, 0.0, 3.0, 0.0]
     assert h["edges"] == [0.0, 2.0, 4.0, 6.0, 8.0]
+
+
+# ------------------------------------------------------- spans and scopes
+
+
+def _run(script: str, *args: str, timeout: int = 300) -> str:
+    """Run ``script`` in a fresh interpreter, bounded by ``timeout``: a
+    profiler or a forced device count must not touch this process."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run(
+        [sys.executable, "-c", script, *args],
+        capture_output=True, text=True, env=env, timeout=timeout,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    return out.stdout
+
+
+_PROFILED = textwrap.dedent(
+    """
+    import glob, json, sys
+    import jax, numpy as np
+    from repro.core import topology as T
+    from repro.core.commplan import FailureModel, compile_plan
+    from repro.core.initialisation import InitConfig
+    from repro.data import batch_index_schedule, mnist_like, node_datasets
+    from repro.fed import init_fl_state, make_eval_fn, make_round_fn, run_trajectory
+    from repro.models.paper_models import classifier_loss, init_mlp, mlp_forward
+    from repro.optim import sgd
+
+    N, PER_NODE, BS, B_LOCAL, ROUNDS = 6, 48, 8, 2, 9
+    ds = mnist_like(N * PER_NODE + 64, seed=0)
+    xs, ys = node_datasets(ds, [np.arange(i * PER_NODE, (i + 1) * PER_NODE) for i in range(N)])
+    loss_fn = lambda p, b: classifier_loss(mlp_forward(p, b[0]), b[1])
+    opt = sgd(1e-3, 0.5)
+    init_one = lambda k: init_mlp(InitConfig("he_normal", 2.0), k, hidden=(32,))
+    plan = compile_plan(T.ring(N), backend="dense", failures=FailureModel(link_p=0.8))
+    rf = make_round_fn(loss_fn, opt, plan)
+    sched = batch_index_schedule(PER_NODE, N, BS, ROUNDS * B_LOCAL, seed=0)
+
+    def run():
+        s = init_fl_state(jax.random.PRNGKey(1), N, init_one, opt)
+        s, _ = run_trajectory(
+            s, rf, xs, ys, sched, n_rounds=ROUNDS, eval_every=2, eval_fn=make_eval_fn(loss_fn),
+            eval_batch=(ds.x[-64:], ds.y[-64:]), track_sigmas=True, chunk_size=3,
+            b_local=B_LOCAL, on_chunk=lambda *a: None,
+        )
+        return jax.block_until_ready(s)
+
+    off = run()
+    jax.profiler.start_trace(sys.argv[1])
+    on = run()
+    jax.profiler.stop_trace()
+    pd = jax.profiler.ProfileData.from_file(
+        glob.glob(sys.argv[1] + "/plugins/profile/*/*.xplane.pb")[0]
+    )
+    spans = [
+        (e.name, e.start_ns, e.start_ns + e.duration_ns)
+        for plane in pd.planes if plane.name.startswith("/host")
+        for line in plane.lines for e in line.events if e.name.startswith("dfl.")
+    ]
+    same = all(
+        np.array_equal(np.asarray(a), np.asarray(b))
+        for a, b in zip(jax.tree_util.tree_leaves(off), jax.tree_util.tree_leaves(on))
+    )
+    print(json.dumps({"spans": sorted(spans, key=lambda s: s[1]), "same": same}))
+    """
+)
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """A 3-chunk ``run_trajectory`` with the profiler off, then on."""
+    out = _run(_PROFILED, str(tmp_path_factory.mktemp("prof")))
+    return json.loads(out.strip().splitlines()[-1])
+
+
+def test_profiled_run_nests_chunk_spans(profiled):
+    from repro.obs.trace import SPANS
+
+    spans = profiled["spans"]
+    assert {s[0] for s in spans} <= set(SPANS)
+    (traj,) = [s for s in spans if s[0] == "dfl.trajectory"]
+    chunks = [s for s in spans if s[0] == "dfl.chunk"]
+    assert len(chunks) == 3
+    inside = lambda s, c: c[1] <= s[1] and s[2] <= c[2]  # noqa: E731
+    for c in chunks:
+        assert inside(c, traj)
+        kids = [s for s in spans if s[0].startswith("dfl.chunk.") and inside(s, c)]
+        assert [k[0] for k in kids] == ["dfl.chunk.slice", "dfl.chunk.dispatch", "dfl.chunk.fetch"]
+        assert kids[0][2] <= kids[1][1] and kids[1][2] <= kids[2][1]
+    (asm,) = [s for s in spans if s[0] == "dfl.assemble"]
+    assert inside(asm, traj) and asm[1] >= max(c[2] for c in chunks)
+
+
+def test_profiler_does_not_perturb_trajectory(profiled):
+    assert profiled["same"]
+
+
+def test_executor_counts_calls_and_chunk_traces(setup):
+    from repro.obs.trace import COUNTERS, counts
+
+    xs, ys, test, loss_fn, opt, init_one = setup
+    rf = make_round_fn(loss_fn, opt, compile_plan(T.ring(N), backend="dense"))
+    before = counts()
+    for _ in range(2):
+        state = init_fl_state(jax.random.PRNGKey(0), N, init_one, opt)
+        run_trajectory(state, rf, xs, ys, _sched(), n_rounds=ROUNDS, chunk_size=4)
+    after = counts()
+    assert set(after) <= set(COUNTERS)
+    for name in ("dfl.calls", "dfl.chunk_traces"):  # one chunk shape: one trace a call
+        assert after[name] - before.get(name, 0) == 2
+
+
+def test_round_body_scopes_in_chunk_lowering(setup):
+    from repro.fed.executor import _as_round_schedule, _build_chunk_fn, _device_data
+    from repro.obs.trace import SCOPES
+
+    xs, ys, test, loss_fn, opt, init_one = setup
+    rf = make_round_fn(loss_fn, opt, compile_plan(T.ring(N), backend="dense"), link_p=0.5)
+    chunk, _, _, _ = _build_chunk_fn(
+        rf, N, make_eval_fn(loss_fn), True, wire_fn=make_wire_fn(rf.plan)
+    )
+    state = init_fl_state(jax.random.PRNGKey(0), N, init_one, opt)
+    sched = jnp.asarray(_as_round_schedule(_sched(), ROUNDS, B_LOCAL))[:2]
+    text = chunk.lower(
+        state, sched, jnp.ones(2, bool), _device_data(xs, ys, test)
+    ).as_text(debug_info=True)
+    for name in set(SCOPES) - {"halo_exchange"}:  # the halo lives in the sharded mix
+        assert re.search(rf'["/]{name}/', text), name
+
+
+_SHARDED_SCOPES = textwrap.dedent(
+    """
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import glob, re
+    import jax, numpy as np
+    jax.config.update("jax_dump_ir_to", sys.argv[1])
+    from repro.core import topology as T
+    from repro.core.commplan import FailureModel, compile_plan
+    from repro.core.initialisation import InitConfig
+    from repro.data import batch_index_schedule, mnist_like, node_datasets
+    from repro.fed import init_fl_state, make_eval_fn, run_sharded_trajectory
+    from repro.models.paper_models import classifier_loss, init_mlp, mlp_forward
+    from repro.obs.trace import SCOPES, counts
+    from repro.optim import sgd
+
+    N, PER_NODE, BS, B_LOCAL, ROUNDS = 8, 16, 8, 2, 2
+    ds = mnist_like(N * PER_NODE + 16, seed=0)
+    xs, ys = node_datasets(ds, [np.arange(i * PER_NODE, (i + 1) * PER_NODE) for i in range(N)])
+    loss_fn = lambda p, b: classifier_loss(mlp_forward(p, b[0]), b[1])
+    opt = sgd(1e-3, 0.5)
+    plan = compile_plan(
+        T.random_k_regular(N, 4, seed=1), backend="sparse", failures=FailureModel(link_p=0.8)
+    ).shard(n_shards=4)
+    s0 = init_fl_state(
+        jax.random.PRNGKey(0), N, lambda k: init_mlp(InitConfig("he_normal", 2.0), k, hidden=(16,)), opt
+    )
+    run_sharded_trajectory(
+        s0, loss_fn, opt, plan, xs, ys,
+        batch_index_schedule(PER_NODE, N, BS, ROUNDS * B_LOCAL, seed=0),
+        n_rounds=ROUNDS, eval_every=1, eval_fn=make_eval_fn(loss_fn),
+        eval_batch=(ds.x[-16:], ds.y[-16:]), track_sigmas=True, b_local=B_LOCAL,
+    )
+    texts = [open(f).read() for f in glob.glob(sys.argv[1] + "/*.mlir")]
+    (text,) = [t for t in texts if "dfl_local" in t]
+    missing = [s for s in SCOPES if s != "dfl_wire" and not re.search('["/]' + s + '/', text)]
+    assert not missing, missing
+    assert re.search(r'dfl_mix/[^"]*halo_exchange/', text)
+    assert counts() == {"dfl.calls": 1, "dfl.chunk_traces": 1}, counts()
+    print("SHARDED_SCOPES_OK")
+    """
+)
+
+
+def test_sharded_trajectory_scopes_in_lowering(tmp_path):
+    """Four virtual devices: the sharded round body carries every scope of
+    the one-device body (the delivered-message replay aside: the halo's
+    wire cost is a plan static) and the halo exchange nests under the mix."""
+    assert "SHARDED_SCOPES_OK" in _run(_SHARDED_SCOPES, str(tmp_path))
 
 
 # ------------------------------------------------------------ run-log export
