@@ -11,8 +11,8 @@ implementing Eq. 2 exactly (DESIGN.md §3):
               topology, O(n²·d); the paper-faithful baseline.
 ``sparse``    CSR/edge-list gather + ``segment_sum`` scatter — O(E·d), makes
               n in the thousands tractable; ``repro.kernels.mix.sparse``
-              supplies the blocked block-sparse Pallas kernel for the TPU
-              rendering of the same contraction.
+              holds a blocked block-sparse Pallas kernel of the same
+              contraction, which no plan dispatches to.
 ``ppermute``  greedy edge colouring → each colour class is a matching = one
               ``ppermute`` round inside ``shard_map``; moves degree·|w| bytes
               per node instead of n·|w|.  Generalises the circulant-only
@@ -57,6 +57,7 @@ __all__ = [
     "FailureModel",
     "PlanSchedule",
     "RoundMap",
+    "auto_backend",
     "compile_plan",
     "compile_schedule",
     "cyclic_map",
@@ -64,6 +65,34 @@ __all__ = [
 ]
 
 BACKENDS = ("dense", "sparse", "ppermute")
+
+# On a TPU the dense mix is one MXU contraction per leaf, O(n²·d), and the
+# sparse mix a gather and scatter of every directed edge row, O((nnz + n)·d).
+# Per unit of d the sparse form costs this many times the dense form's n²
+# term (fitted by a sweep of both on a TPU v5e, PERF.md §6): "auto" goes
+# dense while n² ≤ _TPU_DENSE_KAPPA · (nnz + n).
+_TPU_DENSE_KAPPA = 300
+
+
+def auto_backend(platform: str, n: int, nnz: int) -> str:
+    """The backend ``backend="auto"`` resolves to for ``n`` nodes joined by
+    ``nnz`` directed (receive) edges on ``platform`` (``jax.default_backend()``).
+
+    On the TPU, dense wherever its (n, n) contraction costs less than the
+    sparse edge gather/scatter; elsewhere dense up to n = 64, the crossover
+    the CPU mixing sweep measured (DESIGN.md §3)."""
+    if platform == "tpu":
+        return "dense" if n * n <= _TPU_DENSE_KAPPA * (nnz + n) else "sparse"
+    return "dense" if n <= 64 else "sparse"
+
+
+def _resolve_auto(n: int, nnz: int) -> str:
+    """``auto_backend`` on the default platform, counted in ``repro.obs.trace``."""
+    from repro.obs.trace import count  # local import: repro.obs builds on CommPlan
+
+    backend = auto_backend(jax.default_backend(), n, nnz)
+    count(f"commplan.auto_{backend}")
+    return backend
 
 
 def _draw_failure_masks(
@@ -628,13 +657,12 @@ def compile_plan(
 ) -> CommPlan:
     """Lower a ``Graph`` into an executable ``CommPlan``.
 
-    backend="auto" picks dense for small ensembles (n ≤ 64, where the (n, n)
-    einsum is cheapest and GSPMD-friendliest) and sparse beyond — the
-    crossover the mixing benchmark sweep measures.
+    backend="auto" picks dense or sparse from the platform, n and the
+    directed edge count (``auto_backend``).
     """
     failures = failures or FailureModel()
     if backend == "auto":
-        backend = "dense" if graph.n <= 64 else "sparse"
+        backend = _resolve_auto(graph.n, len(graph.csr()[1]))
     if backend not in BACKENDS:
         raise ValueError(f"unknown mixing backend {backend!r}; expected one of {BACKENDS}")
 
@@ -1174,7 +1202,7 @@ def compile_schedule(
             f"{[g.n for g in graphs]}"
         )
     if backend == "auto":
-        backend = "dense" if graphs[0].n <= 64 else "sparse"
+        backend = _resolve_auto(graphs[0].n, max(len(g.csr()[1]) for g in graphs))
     plans = tuple(
         compile_plan(g, backend=backend, data_sizes=data_sizes, failures=failures)
         for g in graphs

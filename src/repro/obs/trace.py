@@ -52,6 +52,10 @@ SCOPES = {
 COUNTERS = {
     "dfl.calls": "one per executor call; traces_per_call",
     "dfl.chunk_traces": "one per trace of a chunk program's Python body; traces_per_call",
+    "commplan.auto_dense": "one per backend=\"auto\" plan or schedule resolved to dense; "
+                           "which mix a run got",
+    "commplan.auto_sparse": "one per backend=\"auto\" plan or schedule resolved to sparse; "
+                            "which mix a run got",
 }
 
 _counts: collections.Counter = collections.Counter()

@@ -271,3 +271,56 @@ def test_make_round_fn_data_sizes_override_keeps_plan_failures():
     rf_local = jax.jit(make_round_fn(loss_fn, opt, g, aggregate=False))
     state2, _ = rf_local(state0, (x, y))
     assert _max_err(state1.params, state2.params) < 1e-6
+
+
+# ------------------------------------------------------ auto backend choice
+@pytest.mark.parametrize(
+    "platform, graph, want",
+    [
+        ("tpu", lambda: T.barabasi_albert(256, 8, seed=0), "dense"),
+        ("tpu", lambda: T.ring(4096), "sparse"),
+        ("cpu", lambda: T.ring(65), "sparse"),
+        ("cpu", lambda: T.ring(64), "dense"),
+    ],
+    ids=["tpu-ba256", "tpu-ring4096", "cpu-65", "cpu-64"],
+)
+def test_auto_backend_by_platform_n_and_edges(platform, graph, want):
+    from repro.core.commplan import auto_backend
+
+    g = graph()
+    assert auto_backend(platform, g.n, len(g.csr()[1])) == want
+
+
+def test_auto_dense_and_sparse_agree_on_the_cell_graph():
+    """BA n=256 m=8 at link_p 0.9: the rendering "auto" picks on a TPU
+    (dense) mixes, masks and counts the wire as the CPU's (sparse) does."""
+    from repro.obs.wirecost import make_wire_fn
+
+    g = T.barabasi_albert(256, 8, seed=0)
+    fm = FailureModel(link_p=0.9)
+    plans = {b: compile_plan(g, b, failures=fm) for b in ("dense", "sparse")}
+    params = {
+        "w": jax.random.normal(jax.random.PRNGKey(0), (g.n, 6, 3)),
+        "b": jax.random.normal(jax.random.PRNGKey(1), (g.n, 5)),
+    }
+    for r in range(3):
+        key = jax.random.PRNGKey(100 + r)
+        dense, sparse = (plans[b].mix(params, key) for b in ("dense", "sparse"))
+        assert _max_err(dense, sparse) < 1e-5, r
+        for a, b in zip(plans["dense"].round_masks(key), plans["sparse"].round_masks(key)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+        wires = [float(make_wire_fn(plans[b])(key, r)) for b in ("dense", "sparse")]
+        assert wires[0] == wires[1] > 0
+
+
+def test_compile_plan_auto_counts_its_choice():
+    from repro.obs.trace import COUNTERS, counts
+
+    before = counts()
+    assert compile_plan(T.ring(8)).backend == "dense"  # CPU: n <= 64
+    assert compile_plan(T.ring(65), backend="auto").backend == "sparse"
+    compile_plan(T.ring(65), backend="sparse")  # a named backend is not counted
+    after = counts()
+    for name, delta in (("commplan.auto_dense", 1), ("commplan.auto_sparse", 1)):
+        assert name in COUNTERS
+        assert after.get(name, 0) - before.get(name, 0) == delta

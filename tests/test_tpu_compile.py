@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from repro.core import topology as T
-from repro.core.commplan import FailureModel, compile_plan
+from repro.core.commplan import FailureModel, auto_backend, compile_plan
 from repro.core.initialisation import InitConfig, gain_from_graph
 from repro.core.mixing import receive_matrix
 from repro.fed import init_fl_state, make_eval_fn, make_round_fn
@@ -95,13 +95,10 @@ def test_mixing_kernel_compiles_for_v5e(one_chip, kernel):
     assert _footprint(compiled) <= HBM_BYTES
 
 
-def test_round_chunk_compiles_for_v5e(one_chip):
-    """The fused executor's round chunk for chip_smoke phase (a) — 256 paper
-    MLPs on a Barabási–Albert graph, failure-masked sparse mix, eval and σ
-    channels on — fits one chip."""
-    graph = T.barabasi_albert(N_TRAIN, 8, seed=0)
-    plan = compile_plan(graph, failures=FailureModel(link_p=0.9))
-    assert plan.backend == "sparse"
+def _round_chunk(one_chip, plan):
+    """chip_smoke phase (a)'s fused round chunk over ``plan``, compiled for
+    the described chip."""
+    graph = plan.graph
     loss_fn = lambda p, b: classifier_loss(mlp_forward(p, b[0]), b[1])  # noqa: E731
     opt = sgd(1e-3, 0.5)
     init_one = lambda k: init_mlp(InitConfig("he_normal", gain_from_graph(graph)), k)  # noqa: E731
@@ -126,4 +123,25 @@ def test_round_chunk_compiles_for_v5e(one_chip):
     assert sum(
         np.prod(l.shape) for l in jax.tree_util.tree_leaves(state.params)
     ) == N_TRAIN * D_MLP
-    assert _footprint(compiled) <= HBM_BYTES
+    return compiled
+
+
+def test_round_chunk_compiles_for_v5e(one_chip):
+    """The fused executor's round chunk for chip_smoke phase (a) — 256 paper
+    MLPs on a Barabási–Albert graph, failure-masked sparse mix, eval and σ
+    channels on — fits one chip."""
+    graph = T.barabasi_albert(N_TRAIN, 8, seed=0)
+    plan = compile_plan(graph, failures=FailureModel(link_p=0.9))
+    assert plan.backend == "sparse"
+    assert _footprint(_round_chunk(one_chip, plan)) <= HBM_BYTES
+
+
+def test_round_chunk_with_tpu_auto_mix_compiles_for_v5e(one_chip):
+    """The same chunk over the plan ``auto`` gives on a TPU — the dense
+    masked mix — fits one chip without the sparse mix's (edges, d)
+    temporaries: under 4 GiB, where the sparse chunk books about 15 GB."""
+    graph = T.barabasi_albert(N_TRAIN, 8, seed=0)
+    backend = auto_backend("tpu", graph.n, len(graph.csr()[1]))
+    assert backend == "dense"
+    plan = compile_plan(graph, backend=backend, failures=FailureModel(link_p=0.9))
+    assert _footprint(_round_chunk(one_chip, plan)) <= 4 * 2**30
