@@ -388,7 +388,9 @@ def run(args: argparse.Namespace) -> tuple[DFLState, dict[str, list]]:
             fwd = cnn_forward
         else:
             init_with = lambda c: (
-                lambda k: init_vgg16(c, k, image_shape=ds.x.shape[1:], n_classes=ds.n_classes, width_mult=0.25)
+                lambda k: init_vgg16(
+                    c, k, image_shape=ds.x.shape[1:], n_classes=ds.n_classes, width_mult=1.0, fc_dim=4096
+                )
             )
             fwd = vgg16_forward
         loss_fn = lambda p, b: classifier_loss(fwd(p, b[0]), b[1])

@@ -3,10 +3,13 @@
 * MLP    — 4 fully-connected layers (512, 256, 128 hidden; 10 out), ReLU.
 * CNN    — 3 conv layers (32/64/64 ch, 3×3, pad 1) + FC 128, 64, out.
 * VGG16  — Simonyan & Zisserman cfg-D, with a width multiplier for
-           CPU-tractable validation runs (full width exercised via shapes).
+           CPU-tractable validation runs (published widths at 1.0).
 
 All weights go through the gain-corrected He initialiser — these are the
-models Figures 1–4, 6, 7 are made with.
+models Figures 1–4, 6, 7 are made with.  The CNN and VGG forwards name
+their device scopes (``repro.obs.trace.SCOPES``): ``model_conv`` (one
+convolution with its bias and ReLU), ``model_pool`` (one 2×2 max-pool) and
+``model_dense`` (one classifier layer); the MLP's forward has none.
 """
 from __future__ import annotations
 
@@ -79,8 +82,20 @@ def _conv(p: PyTree, x: jax.Array) -> jax.Array:
     return y + p["b"]
 
 
+def _conv_relu(p: PyTree, x: jax.Array) -> jax.Array:
+    with jax.named_scope("model_conv"):
+        return jax.nn.relu(_conv(p, x))
+
+
 def _maxpool2(x: jax.Array) -> jax.Array:
-    return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+    with jax.named_scope("model_pool"):
+        return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+
+
+def _dense(p: PyTree, x: jax.Array, relu: bool) -> jax.Array:
+    with jax.named_scope("model_dense"):
+        x = x @ p["w"] + p["b"]
+        return jax.nn.relu(x) if relu else x
 
 
 def init_cnn(
@@ -112,16 +127,12 @@ def cnn_forward(params: PyTree, x: jax.Array) -> jax.Array:
     """x (B, H, W, C) → logits."""
     i = 0
     while f"conv{i}" in params:
-        x = jax.nn.relu(_conv(params[f"conv{i}"], x))
-        x = _maxpool2(x)
+        x = _maxpool2(_conv_relu(params[f"conv{i}"], x))
         i += 1
     x = x.reshape(x.shape[0], -1)
     i = 0
     while f"fc{i}" in params:
-        p = params[f"fc{i}"]
-        x = x @ p["w"] + p["b"]
-        if f"fc{i+1}" in params:
-            x = jax.nn.relu(x)
+        x = _dense(params[f"fc{i}"], x, relu=f"fc{i+1}" in params)
         i += 1
     return x
 
@@ -167,14 +178,11 @@ def vgg16_forward(params: PyTree, x: jax.Array) -> jax.Array:
         if entry == "M":
             x = _maxpool2(x)
             continue
-        x = jax.nn.relu(_conv(params[f"conv{conv_i}"], x))
+        x = _conv_relu(params[f"conv{conv_i}"], x)
         conv_i += 1
     x = x.reshape(x.shape[0], -1)
     for i in range(3):
-        p = params[f"fc{i}"]
-        x = x @ p["w"] + p["b"]
-        if i < 2:
-            x = jax.nn.relu(x)
+        x = _dense(params[f"fc{i}"], x, relu=i < 2)
     return x
 
 

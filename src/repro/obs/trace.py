@@ -46,6 +46,11 @@ SCOPES = {
     "dfl_wire": "the replay of the round's delivered-message count; bookkeeping_ms_per_round",
     "dfl_eval": "the gated test loss; eval_ms_per_eval",
     "dfl_sigma": "the gated sigma_ap/sigma_an moments; bookkeeping_ms_per_round",
+    # the model's own (repro.models.paper_models CNN and VGG forwards); under
+    # a transformation the path part reads e.g. transpose(jvp(model_conv))
+    "model_conv": "one convolution with its bias and ReLU; conv_ms_per_round, conv_roofline",
+    "model_pool": "one 2x2 max-pool (its backward a select-and-scatter); pool_ms_per_round",
+    "model_dense": "one classifier layer; the operator's timeline",
 }
 
 # counters: name -> what increments it; what reads it

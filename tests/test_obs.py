@@ -429,8 +429,37 @@ def test_round_body_scopes_in_chunk_lowering(setup):
     text = chunk.lower(
         state, sched, jnp.ones(2, bool), _device_data(xs, ys, test)
     ).as_text(debug_info=True)
-    for name in set(SCOPES) - {"halo_exchange"}:  # the halo lives in the sharded mix
+    # the halo lives in the sharded mix; the model scopes in the CNN and VGG forwards
+    model = {s for s in SCOPES if s.startswith("model_")}
+    assert model == {"model_conv", "model_pool", "model_dense"}
+    for name in set(SCOPES) - {"halo_exchange"} - model:
         assert re.search(rf'["/]{name}/', text), name
+    assert "model_" not in text
+
+
+def test_model_scopes_in_vgg_chunk_lowering():
+    """The VGG forward's scopes reach the local steps' ops wrapped in the
+    transformations' names (what ``chipbench/scopes.py`` strips)."""
+    from repro.fed.executor import _as_round_schedule, _build_chunk_fn, _device_data
+    from repro.models.paper_models import init_vgg16, vgg16_forward
+
+    n, per_node = 2, 8
+    xs = jnp.zeros((n, per_node, 32, 32, 3))
+    ys = jnp.zeros((n, per_node), jnp.int32)
+    loss_fn = lambda p, b: classifier_loss(vgg16_forward(p, b[0]), b[1])  # noqa: E731
+    opt = sgd(1e-3, 0.5)
+    init_one = lambda k: init_vgg16(InitConfig("he_normal", 1.0), k, width_mult=0.125)  # noqa: E731
+    rf = make_round_fn(loss_fn, opt, compile_plan(T.ring(n), backend="dense"))
+    chunk, _, _, _ = _build_chunk_fn(rf, n, make_eval_fn(loss_fn), False)
+    state = init_fl_state(jax.random.PRNGKey(0), n, init_one, opt)
+    sched = batch_index_schedule(per_node, n, 4, 2, seed=0)
+    text = chunk.lower(
+        state, jnp.asarray(_as_round_schedule(sched, 1, 2)), jnp.ones(1, bool),
+        _device_data(xs, ys, (xs[0], ys[0])),
+    ).as_text(debug_info=True)
+    for name in ("model_conv", "model_pool", "model_dense"):
+        for wrapped in (f"jvp({name})", f"transpose(jvp({name}))", f"vmap({name})"):
+            assert f'"{wrapped}/' in text, wrapped
 
 
 _SHARDED_SCOPES = textwrap.dedent(
@@ -468,7 +497,8 @@ _SHARDED_SCOPES = textwrap.dedent(
     )
     texts = [open(f).read() for f in glob.glob(sys.argv[1] + "/*.mlir")]
     (text,) = [t for t in texts if "dfl_local" in t]
-    missing = [s for s in SCOPES if s != "dfl_wire" and not re.search('["/]' + s + '/', text)]
+    missing = [s for s in SCOPES if s != "dfl_wire" and not s.startswith("model_")
+               and not re.search('["/]' + s + '/', text)]
     assert not missing, missing
     assert re.search(r'dfl_mix/[^"]*halo_exchange/', text)
     assert counts() == {"dfl.calls": 1, "dfl.chunk_traces": 1}, counts()
