@@ -1,6 +1,6 @@
 """Bring-up smoke run of the DFL trainer on a TPU.
 
-    python chip_smoke.py               # phases (a)-(d) on one TPU chip
+    python chip_smoke.py               # phases (a)-(e) on one TPU chip
     python chip_smoke.py --four-chips  # node-sharded trainer over four chips
 
 One process drives every phase through the entry points a user calls:
@@ -12,7 +12,10 @@ One process drives every phase through the entry points a user calls:
     at ``highest`` matmul precision, compared loss by loss;
 (c) ``examples/quickstart.py``: He vs gain-corrected init as one sweep — the
     paper's headline effect (He stays at log 10, the corrected run descends);
-(d) ``launch.serve``: training and consensus-routed serving in one event scan.
+(d) ``launch.serve``: training and consensus-routed serving in one event scan;
+(e) the minibatch selection the executor resolves on the chip for the
+    ``mlp_ba256_linkfail`` cell's data (256 nodes × 256 items of 28×28×1
+    f32, 8 × 16 drawn a round) against the plain gather, bit for bit.
 
 ``--four-chips`` runs (a)'s configuration through ``run_sharded_trajectory``
 over a 4-device node mesh and through ``run_trajectory`` on one chip, and
@@ -46,12 +49,14 @@ if _platforms and "cpu" not in _platforms.split(","):
 sys.path.insert(0, str(SRC))
 
 import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.core.commplan import FailureModel, compile_plan  # noqa: E402
 from repro.core.initialisation import InitConfig, gain_from_graph  # noqa: E402
 from repro.data import batch_index_schedule, mnist_like, node_datasets, partition_iid  # noqa: E402
 from repro.fed import (  # noqa: E402
+    batching,
     init_fl_state,
     make_eval_fn,
     make_round_fn,
@@ -87,6 +92,8 @@ CORRECTED_MARGIN = 0.5
 # (d): 16-node ring, consensus router
 SERVE_ARGV = ["--nodes", "16", "--topology", "ring", "--horizon", "5", "--qps", "4",
               "--router", "consensus"]
+# (e): the cell's nodes, items and draws (chipbench/traffic/ba256_linkfail.json)
+SELECT = dict(nodes=256, items_per_node=256, rounds=4)
 # --four-chips: sharded vs one-chip parameters, max |Δ| over max |p|
 SHARD_RTOL = 1e-3
 
@@ -215,6 +222,57 @@ def phase_serve(argv: list[str]) -> dict:
     )
 
 
+def phase_batch_select(nodes: int, items_per_node: int, rounds: int) -> dict:
+    """(e) ``batching.draw_batch`` as the executor resolves it on this
+    platform (on a TPU the one-hot contraction) against ``xs[node, idx]``,
+    over ``rounds`` rounds of the cell's batch order: the f32 items, the
+    bf16 the model's matmuls read, and the same items scaled over 40
+    binades.  Bits that differ are counted."""
+    ds = mnist_like(nodes * items_per_node, seed=0)
+    xs = ds.x.reshape((nodes, items_per_node) + ds.x.shape[1:])
+    ys = ds.y.reshape(nodes, items_per_node)
+    sched = batch_index_schedule(items_per_node, nodes, 16, rounds * 8, seed=0)
+    idx = sched.reshape(rounds, 8, nodes, 16).transpose(0, 2, 1, 3)
+    drawn = idx[0, 0].size
+    rendering = batching.select_rendering(
+        jax.default_backend(), xs.dtype, items_per_node, drawn
+    )
+    wide = xs * np.float32(10.0) ** np.random.default_rng(0).integers(
+        -20, 20, xs.shape
+    ).astype(np.float32)
+
+    @jax.jit
+    def select(x, y, i):
+        flat, item_shape = batching.lane_dense(x)
+        bx, by = batching.draw_batch(flat, y, i, item_shape)
+        return bx, bx.astype(jnp.bfloat16), by
+
+    @jax.jit
+    def gather(x, y, i):
+        node = jnp.arange(x.shape[0])[:, None, None]
+        return x[node, i], x[node, i].astype(jnp.bfloat16), y[node, i]
+
+    differ = {"f32": 0, "bf16": 0, "labels": 0, "wide_f32": 0}
+    for r in range(rounds):
+        i = jnp.asarray(idx[r])
+        for tag, data in (("", xs), ("wide_", wide)):
+            got = [np.asarray(a) for a in select(data, ys, i)]
+            want = [np.asarray(a) for a in gather(data, ys, i)]
+            differ[tag + "f32"] += int(np.count_nonzero(
+                got[0].view(np.uint32) != want[0].view(np.uint32)))
+            if not tag:
+                differ["bf16"] += int(np.count_nonzero(
+                    got[1].view(np.uint16) != want[1].view(np.uint16)))
+                differ["labels"] += int(np.count_nonzero(got[2] != want[2]))
+    on_tpu = jax.default_backend() == "tpu"
+    return _checked(
+        {"rendering": rendering, "values_compared": int(rounds * nodes * drawn * xs[0, 0].size),
+         "bits_differ": differ},
+        contraction_on_tpu=rendering == "onehot" or not on_tpu,
+        bit_equal=not any(differ.values()),
+    )
+
+
 def phase_four_chips(
     nodes: int, rounds: int, items_per_node: int, n_shards: int = 4, backend: str = "sparse"
 ) -> dict:
@@ -328,6 +386,7 @@ def main(argv: list[str] | None = None) -> int:
                 TRAIN["nodes"], TRAIN["items_per_node"], a["train_loss"])),
             ("c_headline", phase_headline),
             ("d_serve", lambda: phase_serve(SERVE_ARGV)),
+            ("e_batch_select", lambda: phase_batch_select(**SELECT)),
         ]
     if not run_phases(phases, clock):
         return 1

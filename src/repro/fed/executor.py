@@ -10,7 +10,8 @@ trajectory (DESIGN.md §11):
   opt reinit run as chunked ``lax.scan`` inside a single jitted,
   buffer-donated call; Python re-enters once per *chunk*, not per round.
 * **on-device data sampling** — the per-node datasets live on device and
-  each round's minibatches are taken by gather from the precomputed
+  each round's minibatches are drawn (``batching.draw_batch``: a one-hot
+  contraction on the TPU, a gather elsewhere) from the precomputed
   ``data.pipeline.batch_index_schedule`` (bit-identical order to the host
   iterator for the same seed).
 * **on-device metrics** — periodic eval / σ_an/σ_ap are computed inside the
@@ -66,6 +67,7 @@ from repro.obs.wirecost import (
     static_wire_messages,
 )
 
+from .batching import draw_batch, lane_dense
 from .trainer import DFLState, _local_steps, init_fl_state, make_round_fn, sigma_metrics
 
 PyTree = Any
@@ -245,7 +247,9 @@ def _build_chunk_fn(
     batch enter as jit arguments, never as closed-over constants, so the
     chunk compiles from their shapes alone (``tests/test_tpu_compile.py``
     compiles it for a described TPU) and one compiled program serves every
-    dataset of that shape.
+    dataset of that shape.  The chunk lays ``xs`` out lane-dense, as
+    ``(n, items, F)``, once before its scan; ``batching.draw_batch`` draws
+    each round's minibatches from it.
 
     The buffers are the :class:`repro.obs.Recorder`'s channels — the legacy
     train/eval/σ set (bit-identical to the hand-rolled outs this replaced)
@@ -253,21 +257,15 @@ def _build_chunk_fn(
     traced from the same ``k_mix`` the round consumes.  Returns
     ``(jitted chunk, donate, raw chunk, recorder)``.
     """
-    node_idx = jnp.arange(n_nodes)[:, None]
     rec = Recorder(
         MetricsSpec.legacy(eval_fn is not None, track_sigmas, wire=wire_fn is not None)
     )
 
-    def body(data, state, per_round):
+    def body(data, item_shape, state, per_round):
         xs, ys, eval_batch = data
         idx, do_eval = per_round
-        # idx (n, b, bs) → ((n, b, bs, *feat), (n, b, bs))
         with jax.named_scope("dfl_batch"):
-            flat = idx.reshape(n_nodes, -1)
-            batch = (
-                xs[node_idx, flat].reshape(idx.shape + xs.shape[2:]),
-                ys[node_idx, flat].reshape(idx.shape + ys.shape[2:]),
-            )
+            batch = draw_batch(xs, ys, idx, item_shape)
 
         def gated_metrics(params):
             vals = {}
@@ -303,7 +301,12 @@ def _build_chunk_fn(
 
     def chunk_inner(state, sched_chunk, mask_chunk, data):
         count("dfl.chunk_traces")
-        return jax.lax.scan(partial(body, data), state, (sched_chunk, mask_chunk))
+        xs, ys, eval_batch = data
+        with jax.named_scope("dfl_batch"):
+            xs, item_shape = lane_dense(xs)
+        return jax.lax.scan(
+            partial(body, (xs, ys, eval_batch), item_shape), state, (sched_chunk, mask_chunk)
+        )
 
     chunk = chunk_inner
     if sweep:
@@ -551,7 +554,7 @@ def run_sharded_trajectory(
         has_eval = eval_fn is not None
         failures_active = plan.failures.active
         mask_np = cfg.eval_mask()
-        node_idx = jnp.arange(nps)[:, None]
+        xs_d, item_shape = lane_dense(xs_d)
         comp = compression if (compression is not None and compression.active) else None
 
         def sharded_sigmas(params):
@@ -581,9 +584,7 @@ def run_sharded_trajectory(
             with jax.named_scope("dfl_round"):
                 rng, k_mix = jax.random.split(rng)
             with jax.named_scope("dfl_batch"):
-                flat = idx.reshape(nps, -1)
-                bx = xs_l[node_idx, flat].reshape(idx.shape + xs_l.shape[2:])
-                by = ys_l[node_idx, flat].reshape(idx.shape + ys_l.shape[2:])
+                bx, by = draw_batch(xs_l, ys_l, idx, item_shape)
             with jax.named_scope("dfl_local"):
                 params, opt_state, losses = jax.vmap(partial(_local_steps, loss_fn, optimizer))(
                     params, opt_state, (bx, by)
@@ -1113,7 +1114,7 @@ def run_elastic_trajectory(
         mask_np = cfg.eval_mask()
         sched_np = _as_round_schedule(schedule, n_rounds, b_local)
         xs_d, ys_d, eval_d = _device_data(xs, ys, eval_batch)
-        node_idx = jnp.arange(n_nodes)[:, None]
+        xs_d, item_shape = lane_dense(xs_d)
         n_edges = plan.n_edges_env if scheduled else plan.n_edges
         if trivial_faults:
             node_up = np.ones((n_rounds, n_nodes), bool)
@@ -1145,12 +1146,6 @@ def run_elastic_trajectory(
                 new, old,
             )
 
-        def gather_batch(idx):
-            flat = idx.reshape(n_nodes, -1)
-            bx = xs_d[node_idx, flat].reshape(idx.shape + xs_d.shape[2:])
-            by = ys_d[node_idx, flat].reshape(idx.shape + ys_d.shape[2:])
-            return bx, by
-
         def body(carry, per_round):
             if comp is not None:
                 params, opt_state, rng, sketches, mirror = carry
@@ -1181,7 +1176,7 @@ def run_elastic_trajectory(
                 )
 
             # 2. local phase at the full envelope; non-members are frozen
-            bx, by = gather_batch(idx)
+            bx, by = draw_batch(xs_d, ys_d, idx, item_shape)
             new_p, new_o, losses = jax.vmap(partial(_local_steps, loss_fn, optimizer))(
                 params, opt_state, (bx, by)
             )

@@ -11,7 +11,8 @@ executors' host spans (``dfl.trajectory``, ``dfl.chunk`` and its slice,
 dispatch, fetch and checkpoint, ``dfl.assemble``), the round body's device
 scopes (``dfl_round``, ``dfl_batch``, ``dfl_local``, ``dfl_mix``,
 ``dfl_reinit``, ``dfl_wire``, ``dfl_eval``, ``dfl_sigma``) and the
-``dfl.calls``/``dfl.chunk_traces`` counters; a profiler trace records them
+``dfl.calls``/``dfl.chunk_traces`` and ``batch.select_onehot``/
+``batch.select_gather`` counters; a profiler trace records them
 on one clock.
 """
 

@@ -38,7 +38,8 @@ SPANS = {
 SCOPES = {
     "dfl_round": "the round's scalars: PRNG split, round counter, loss means; "
                  "bookkeeping_ms_per_round",
-    "dfl_batch": "the per-node minibatch gather; bookkeeping_ms_per_round",
+    "dfl_batch": "minibatch selection (one-hot contraction or gather); "
+                 "bookkeeping_ms_per_round",
     "dfl_local": "the local SGD steps of every node; local_ms_per_round",
     "dfl_mix": "DecAvg with its link masks; mix_ms_per_round",
     "halo_exchange": "the sharded mix's cross-shard rows, under dfl_mix; the operator's timeline",
@@ -61,6 +62,10 @@ COUNTERS = {
                            "which mix a run got",
     "commplan.auto_sparse": "one per backend=\"auto\" plan or schedule resolved to sparse; "
                             "which mix a run got",
+    "batch.select_onehot": "one per chunk program traced whose minibatches are a one-hot "
+                           "contraction (repro.fed.batching); which selection a run got",
+    "batch.select_gather": "one per chunk program traced whose minibatches are a gather "
+                           "(repro.fed.batching); which selection a run got",
 }
 
 _counts: collections.Counter = collections.Counter()

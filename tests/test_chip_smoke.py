@@ -86,6 +86,17 @@ def test_phase_serve_tiny(smoke):
     assert out["failed"] == [] and out["served"] > 0
 
 
+@pytest.mark.parametrize("rendering", ["onehot", "gather"])
+def test_phase_batch_select_tiny(smoke, monkeypatch, rendering):
+    """Phase (e) with the rule forced to each rendering on the CPU (where
+    it picks the gather): no bit differs from the plain gather."""
+    monkeypatch.setattr(smoke.batching, "select_rendering", lambda *a: rendering)
+    out = smoke.phase_batch_select(nodes=4, items_per_node=32, rounds=2)
+    assert out["failed"] == []
+    assert out["rendering"] == rendering
+    assert out["values_compared"] == 2 * 4 * 128 * 784
+
+
 def test_phase_four_chips_on_four_host_devices():
     """The --four-chips phase over 4 forced host devices: the sharded and
     one-device executors agree bitwise on the CPU, the state sits on all

@@ -10,18 +10,28 @@ order: they agree within ``METRIC_ULPS`` units in the last place
 (DESIGN.md §11).  Lanes of one vmapped sweep are not bitwise either: XLA
 may round each lane of a batched contraction differently.
 """
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.core import topology as T
 from repro.core.commplan import compile_plan
+from repro.core.faults import crash_burst
+from repro.core.membership import membership_schedule
 from repro.core.initialisation import InitConfig
 from repro.data import batch_index_schedule, mnist_like, node_batch_iterator, node_datasets
 from repro.fed import (
+    batching,
     init_fl_state,
     make_eval_fn,
     make_round_fn,
+    run_elastic_trajectory,
     run_sweep,
     run_trajectory,
     stack_states,
@@ -202,3 +212,155 @@ def test_no_eval_history_is_empty(setup):
     state = init_fl_state(jax.random.PRNGKey(0), N, init_one, opt)
     _, hist = run_trajectory(state, rf, xs, ys, _schedule(), n_rounds=ROUNDS)
     assert hist["round"] == [] and hist["train_loss"] == []
+
+
+# ------------------------------------------------ minibatch selection
+# the rendering draw_batch resolves on a TPU, forced here on the CPU by
+# replacing the rule (the helper looks it up at each trace)
+def _force(monkeypatch, rendering):
+    monkeypatch.setattr(batching, "select_rendering", lambda *a: rendering)
+
+
+def _wide_items(rng, shape, dtype):
+    """Items whose f32 values span many binades, with the values whose
+    bf16 hi/mid/lo parts carry across a binade (2^(e+1) − 2^(e−9) + 2^(e−23))."""
+    x = rng.standard_normal(shape) * 10.0 ** rng.uniform(-20, 20, shape)
+    x = x.astype(np.float32).reshape(-1)
+    e = rng.integers(-60, 60, 8).astype(np.float64)
+    x[:8] = (2.0 ** (e + 1) - 2.0 ** (e - 9) + 2.0 ** (e - 23)).astype(np.float32)
+    return jnp.asarray(x.reshape(shape), dtype)
+
+
+@pytest.mark.parametrize("rendering", ["onehot", "gather"])
+@pytest.mark.parametrize("case", ["repeats", "items_eq_drawn"])
+@pytest.mark.parametrize("item_shape", [(28, 28, 1), (32, 32, 3), (784,)], ids=str)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_draw_batch_equals_gather(monkeypatch, dtype, item_shape, case, rendering):
+    """Either rendering returns ``xs[node, idx]`` bit for bit: indices drawn
+    with repeats inside a batch, or every item drawn once."""
+    _force(monkeypatch, rendering)
+    rng = np.random.default_rng(0)
+    n, b, bs = 3, 2, 4
+    items = b * bs if case == "items_eq_drawn" else 3 * b * bs
+    xs = _wide_items(rng, (n, items) + item_shape, dtype)
+    ys = jnp.asarray(rng.integers(0, 10, (n, items)), jnp.int32)
+    if case == "items_eq_drawn":
+        idx = np.stack([rng.permutation(items) for _ in range(n)]).reshape(n, b, bs)
+    else:
+        idx = rng.integers(0, items // 4, (n, b, bs))  # few distinct: repeats
+        idx[:, 0, 1] = idx[:, 0, 0]
+    idx = jnp.asarray(idx, jnp.int32)
+    flat, shape = batching.lane_dense(xs)
+    assert flat.shape == (n, items, int(np.prod(item_shape))) and shape == item_shape
+    bx, by = batching.draw_batch(flat, ys, idx, shape)
+    node = np.arange(n)[:, None, None]
+    assert bx.dtype == xs.dtype and bx.shape == (n, b, bs) + item_shape
+    np.testing.assert_array_equal(np.asarray(bx), np.asarray(xs)[node, np.asarray(idx)])
+    np.testing.assert_array_equal(np.asarray(by), np.asarray(ys)[node, np.asarray(idx)])
+
+
+KAPPA = batching._TPU_ONEHOT_KAPPA
+
+
+@pytest.mark.parametrize(
+    "platform, dtype, items, drawn, want",
+    [
+        # both cells: 256 items a node, 8 × 16 drawn; F (784, 3072) is not read
+        ("tpu", jnp.float32, 256, 8 * 16, "onehot"),
+        ("tpu", jnp.bfloat16, 256, 8 * 16, "onehot"),
+        ("tpu", jnp.int32, 256, 8 * 16, "gather"),  # token ids
+        ("tpu", jnp.float32, KAPPA * 128, 128, "onehot"),
+        ("tpu", jnp.float32, KAPPA * 128 + 1, 128, "gather"),
+        ("tpu", jnp.float32, 50_000, 128, "gather"),
+        ("cpu", jnp.float32, 256, 8 * 16, "gather"),
+    ],
+    ids=["tpu-cells", "tpu-bf16", "tpu-int", "tpu-at-kappa",
+         "tpu-above-kappa", "tpu-many-items", "cpu"],
+)
+def test_select_rendering_rule(platform, dtype, items, drawn, want):
+    assert batching.select_rendering(platform, dtype, items, drawn) == want
+
+
+def test_trajectory_params_bit_equal_across_renderings(monkeypatch, setup):
+    """3 rounds of run_trajectory, forced to each rendering: the same
+    batches, so bit-equal state."""
+    xs, ys, test, loss_fn, opt, init_one = setup
+    rf = make_round_fn(loss_fn, opt, compile_plan(T.complete(N), backend="dense"), link_p=0.5)
+    finals = []
+    for rendering in ("onehot", "gather"):
+        _force(monkeypatch, rendering)
+        state = init_fl_state(jax.random.PRNGKey(0), N, init_one, opt)
+        finals.append(run_trajectory(state, rf, xs, ys, _schedule(rounds=3), n_rounds=3)[0])
+    _assert_states_bit_equal(*finals)
+
+
+def test_elastic_params_bit_equal_across_renderings(monkeypatch, setup):
+    """The elastic executor's inline path (a crash burst), forced to each
+    rendering: bit-equal state."""
+    xs, ys, test, loss_fn, opt, init_one = setup
+    g = T.ring(N)
+    faults = crash_burst(g, 3, at=1, size=2, duration=2, seed=0)
+    finals = []
+    for rendering in ("onehot", "gather"):
+        _force(monkeypatch, rendering)
+        state = init_fl_state(jax.random.PRNGKey(0), N, init_one, opt)
+        finals.append(run_elastic_trajectory(
+            state, loss_fn, opt, compile_plan(g), membership_schedule(N, 3),
+            xs, ys, _schedule(rounds=3), n_rounds=3, faults=faults,
+        )[0])
+    _assert_states_bit_equal(*finals)
+
+
+_SHARDED_RENDERINGS = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, numpy as np
+    from repro.core import topology as T
+    from repro.core.commplan import FailureModel, compile_plan
+    from repro.core.initialisation import InitConfig
+    from repro.data import batch_index_schedule, mnist_like, node_datasets
+    from repro.fed import batching, init_fl_state, run_sharded_trajectory
+    from repro.models.paper_models import classifier_loss, init_mlp, mlp_forward
+    from repro.obs.trace import counts
+    from repro.optim import sgd
+
+    N, PER_NODE, BS, B_LOCAL, ROUNDS = 8, 16, 8, 2, 3
+    ds = mnist_like(N * PER_NODE, seed=0)
+    xs, ys = node_datasets(ds, [np.arange(i * PER_NODE, (i + 1) * PER_NODE) for i in range(N)])
+    loss_fn = lambda p, b: classifier_loss(mlp_forward(p, b[0]), b[1])
+    opt = sgd(1e-3, 0.5)
+    plan = compile_plan(
+        T.random_k_regular(N, 4, seed=1), backend="sparse", failures=FailureModel(link_p=0.8)
+    ).shard(n_shards=4)
+    sched = batch_index_schedule(PER_NODE, N, BS, ROUNDS * B_LOCAL, seed=0)
+    finals = []
+    for rendering in ("onehot", "gather"):
+        batching.select_rendering = lambda *a, r=rendering: r
+        s0 = init_fl_state(
+            jax.random.PRNGKey(0), N,
+            lambda k: init_mlp(InitConfig("he_normal", 2.0), k, hidden=(16,)), opt,
+        )
+        finals.append(run_sharded_trajectory(
+            s0, loss_fn, opt, plan, xs, ys, sched, n_rounds=ROUNDS, b_local=B_LOCAL
+        )[0])
+    for a, b in zip(*(jax.tree_util.tree_leaves(f.params) for f in finals)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    c = counts()
+    assert c["batch.select_onehot"] == c["batch.select_gather"] == 1, c
+    print("SHARDED_RENDERINGS_OK")
+    """
+)
+
+
+def test_sharded_params_bit_equal_across_renderings():
+    """run_sharded_trajectory on 4 virtual devices, 3 rounds, forced to
+    each rendering on every shard's local rows: bit-equal params."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run(
+        [sys.executable, "-c", _SHARDED_RENDERINGS],
+        capture_output=True, text=True, env=env, timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "SHARDED_RENDERINGS_OK" in out.stdout

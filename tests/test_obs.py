@@ -411,8 +411,30 @@ def test_executor_counts_calls_and_chunk_traces(setup):
         run_trajectory(state, rf, xs, ys, _sched(), n_rounds=ROUNDS, chunk_size=4)
     after = counts()
     assert set(after) <= set(COUNTERS)
-    for name in ("dfl.calls", "dfl.chunk_traces"):  # one chunk shape: one trace a call
+    # one chunk shape: one trace a call, each drawing its batches by gather on the CPU
+    for name in ("dfl.calls", "dfl.chunk_traces", "batch.select_gather"):
         assert after[name] - before.get(name, 0) == 2
+    assert after.get("batch.select_onehot", 0) == before.get("batch.select_onehot", 0)
+
+
+@pytest.mark.parametrize("rendering", ["onehot", "gather"])
+def test_batch_selection_counter_follows_rendering(monkeypatch, setup, rendering):
+    """A chunk program forced to one rendering moves that counter alone."""
+    from repro.fed import batching
+    from repro.obs.trace import COUNTERS, counts
+
+    monkeypatch.setattr(batching, "select_rendering", lambda *a: rendering)
+    xs, ys, test, loss_fn, opt, init_one = setup
+    rf = make_round_fn(loss_fn, opt, compile_plan(T.ring(N), backend="dense"))
+    before = counts()
+    state = init_fl_state(jax.random.PRNGKey(0), N, init_one, opt)
+    run_trajectory(state, rf, xs, ys, _sched(), n_rounds=ROUNDS)
+    after = counts()
+    other = "gather" if rendering == "onehot" else "onehot"
+    for r, delta in ((rendering, 1), (other, 0)):
+        name = f"batch.select_{r}"
+        assert name in COUNTERS
+        assert after.get(name, 0) - before.get(name, 0) == delta
 
 
 def test_round_body_scopes_in_chunk_lowering(setup):
@@ -501,7 +523,7 @@ _SHARDED_SCOPES = textwrap.dedent(
                and not re.search('["/]' + s + '/', text)]
     assert not missing, missing
     assert re.search(r'dfl_mix/[^"]*halo_exchange/', text)
-    assert counts() == {"dfl.calls": 1, "dfl.chunk_traces": 1}, counts()
+    assert counts() == {"dfl.calls": 1, "dfl.chunk_traces": 1, "batch.select_gather": 1}, counts()
     print("SHARDED_SCOPES_OK")
     """
 )

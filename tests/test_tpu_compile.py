@@ -22,11 +22,12 @@ from repro.core import topology as T
 from repro.core.commplan import FailureModel, auto_backend, compile_plan
 from repro.core.initialisation import InitConfig, gain_from_graph
 from repro.core.mixing import receive_matrix
-from repro.fed import init_fl_state, make_eval_fn, make_round_fn
+from repro.fed import batching, init_fl_state, make_eval_fn, make_round_fn
 from repro.fed.executor import _build_chunk_fn
 from repro.kernels.mix import bsr_from_dense, mix_bsr, quantised_mix_bsr
 from repro.kernels.mix.mix import mix_matmul
 from repro.models.paper_models import classifier_loss, init_mlp, mlp_forward
+from repro.obs.trace import counts
 from repro.optim import sgd
 
 HBM_BYTES = 16 * 2**30  # TPU v5e, per chip
@@ -95,9 +96,10 @@ def test_mixing_kernel_compiles_for_v5e(one_chip, kernel):
     assert _footprint(compiled) <= HBM_BYTES
 
 
-def _round_chunk(one_chip, plan):
+def _round_chunk(one_chip, plan, item_shape=(784,), rounds=ROUNDS):
     """chip_smoke phase (a)'s fused round chunk over ``plan``, compiled for
-    the described chip."""
+    the described chip; ``item_shape`` and ``rounds`` set the node datasets'
+    items and the chunk's length."""
     graph = plan.graph
     loss_fn = lambda p, b: classifier_loss(mlp_forward(p, b[0]), b[1])  # noqa: E731
     opt = sgd(1e-3, 0.5)
@@ -110,14 +112,14 @@ def _round_chunk(one_chip, plan):
         make_round_fn(loss_fn, opt, plan), N_TRAIN, make_eval_fn(loss_fn), True
     )
     data = (
-        _shape(one_chip, (N_TRAIN, ITEMS, 784)),
+        _shape(one_chip, (N_TRAIN, ITEMS) + item_shape),
         _shape(one_chip, (N_TRAIN, ITEMS), jnp.int32),
-        (_shape(one_chip, (1024, 784)), _shape(one_chip, (1024,), jnp.int32)),
+        (_shape(one_chip, (1024,) + item_shape), _shape(one_chip, (1024,), jnp.int32)),
     )
     compiled = chunk.lower(
         state,
-        _shape(one_chip, (ROUNDS, N_TRAIN, LOCAL, BATCH), jnp.int32),
-        _shape(one_chip, (ROUNDS,), jnp.bool_),
+        _shape(one_chip, (rounds, N_TRAIN, LOCAL, BATCH), jnp.int32),
+        _shape(one_chip, (rounds,), jnp.bool_),
         data,
     ).compile()
     assert sum(
@@ -145,3 +147,18 @@ def test_round_chunk_with_tpu_auto_mix_compiles_for_v5e(one_chip):
     assert backend == "dense"
     plan = compile_plan(graph, backend=backend, failures=FailureModel(link_p=0.9))
     assert _footprint(_round_chunk(one_chip, plan)) <= 4 * 2**30
+
+
+def test_cell_chunk_with_onehot_batches_compiles_for_v5e(one_chip, monkeypatch):
+    """``mlp_ba256_linkfail``'s chunk (10 rounds, (28, 28, 1) f32 items, the
+    dense masked mix) with its minibatches drawn by the one-hot contraction
+    that the TPU's rule picks for it fits one chip under 4 GiB, like the
+    gathering chunk."""
+    graph = T.barabasi_albert(N_TRAIN, 8, seed=0)
+    plan = compile_plan(graph, backend="dense", failures=FailureModel(link_p=0.9))
+    assert batching.select_rendering("tpu", jnp.float32, ITEMS, LOCAL * BATCH) == "onehot"
+    monkeypatch.setattr(batching, "select_rendering", lambda *a: "onehot")
+    before = counts().get("batch.select_onehot", 0)
+    compiled = _round_chunk(one_chip, plan, item_shape=(28, 28, 1), rounds=10)
+    assert counts()["batch.select_onehot"] - before == 1
+    assert _footprint(compiled) <= 4 * 2**30
