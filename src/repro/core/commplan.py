@@ -177,6 +177,21 @@ class CommPlan:
     def n_colors(self) -> int:
         return 0 if self.partners is None else self.partners.shape[0]
 
+    # --------------------------------------------------- one-plan schedule
+    # A CommPlan is the K = 1 schedule: these members mirror PlanSchedule's
+    # with identity meaning, so a caller indexes any plan by round —
+    # ``plan.select(r).<op>(x, plan.round_key(key, r))`` — without asking
+    # its type.
+    @property
+    def plans(self) -> tuple["CommPlan", ...]:
+        return (self,)
+
+    def select(self, round_index=None) -> "CommPlan":
+        return self
+
+    def round_key(self, key: jax.Array | None, round_index=None) -> jax.Array | None:
+        return key
+
     # ------------------------------------------------------------- execution
     def _masked(self, active, edge_live) -> bool:
         """Does this round need the renormalising masked path?  True when the
@@ -939,6 +954,12 @@ class PlanSchedule:
     @property
     def data_sizes(self) -> np.ndarray | None:
         return self.plans[0].data_sizes
+
+    @property
+    def n_edges(self) -> int:
+        """Failure-draw and ``edge_live`` width: the shared edge envelope,
+        as every round's ``select`` view carries it."""
+        return self.n_edges_env
 
     @property
     def graph(self) -> Graph:

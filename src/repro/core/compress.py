@@ -239,7 +239,8 @@ def seed_residual(state, compression: Compression | None):
     return dataclasses.replace(state, residual=init_residuals(state.params))
 
 
-def _mask_rows(mask: jax.Array, new: PyTree, old: PyTree) -> PyTree:
+def mask_rows(mask: jax.Array, new: PyTree, old: PyTree) -> PyTree:
+    """Per node row: ``new`` where the (n,) bool ``mask`` holds, else ``old``."""
     return jax.tree_util.tree_map(
         lambda a, b: jnp.where(mask.reshape((-1,) + (1,) * (a.ndim - 1)), a, b),
         new,
@@ -267,11 +268,19 @@ def compressed_mix_with(
     unchanged; pass ``update_mask`` ((n,) bool) to also freeze their
     mirrors — a node that transmitted nothing updated nobody's copy.
 
+    With ``comp.stream`` the whole pipeline runs per chunk under
+    ``lax.map`` — compress chunk, mix chunk, delta, mirror update — so the
+    largest per-leaf temporary is (n, chunk).  ``mix_fn`` re-derives its
+    failure draws from the same key for every chunk, so all chunks of a
+    round ride one effective operator, identical to the unstreamed path.
+
     Codec ``"none"`` returns ``(mix_fn(params), residual)`` verbatim — the
     bit-identity contract of the uncompressed path.
     """
     if not comp.active:
         return mix_fn(params), residual
+    if comp.stream:
+        return _streamed_mix(mix_fn, params, residual, comp, update_mask)
     if comp.error_feedback:
         delta = jax.tree_util.tree_map(
             lambda x, h: x.astype(jnp.float32) - h, params, residual
@@ -286,7 +295,7 @@ def compressed_mix_with(
             jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), params), comp
         )
     if update_mask is not None:
-        h_new = _mask_rows(update_mask, h_new, residual)
+        h_new = mask_rows(update_mask, h_new, residual)
     mixed = mix_fn(h_new)
     g = jnp.float32(comp.gamma)
     out = jax.tree_util.tree_map(
@@ -298,43 +307,7 @@ def compressed_mix_with(
     return out, h_new
 
 
-def _plan_mix_fn(plan, key, round_index, active, edge_live):
-    if round_index is not None:  # PlanSchedule
-        return lambda p: plan.mix(
-            p, round_index, key, active=active, edge_live=edge_live
-        )
-    return lambda p: plan.mix(p, key, active=active, edge_live=edge_live)
-
-
-def compressed_mix(
-    plan,
-    params: PyTree,
-    residual: PyTree,
-    key: jax.Array | None = None,
-    *,
-    compression: Compression,
-    round_index=None,
-    active: jax.Array | None = None,
-    edge_live: jax.Array | None = None,
-    update_mask: jax.Array | None = None,
-) -> tuple[PyTree, PyTree]:
-    """One compressed DecAvg round over a CommPlan / PlanSchedule.
-
-    The plan-aware form of :func:`compressed_mix_with`: with
-    ``compression.stream`` the whole pipeline runs per chunk under
-    ``lax.map`` — compress chunk, mix chunk, delta, mirror update — so the
-    largest per-leaf temporary is (n, chunk).  Failure draws re-derive from
-    the same ``key`` for every chunk, so all chunks of a round ride one
-    effective operator, identical to the unstreamed path.
-    """
-    mix_fn = _plan_mix_fn(plan, key, round_index, active, edge_live)
-    if not compression.active or not compression.stream:
-        return compressed_mix_with(
-            mix_fn, params, residual, compression, update_mask=update_mask
-        )
-
-    comp = compression
-
+def _streamed_mix(mix_fn, params, residual, comp, update_mask):
     def one_leaf(x, h):
         shape = x.shape
         x3, d = _to_chunks(x.reshape(shape[0], -1).astype(jnp.float32), comp.chunk)
@@ -357,6 +330,29 @@ def compressed_mix(
     return out, new_h
 
 
+def compressed_mix(
+    plan,
+    params: PyTree,
+    residual: PyTree,
+    key: jax.Array | None = None,
+    *,
+    compression: Compression,
+    round_index=None,
+    active: jax.Array | None = None,
+    edge_live: jax.Array | None = None,
+    update_mask: jax.Array | None = None,
+) -> tuple[PyTree, PyTree]:
+    """One compressed DecAvg round over a CommPlan / PlanSchedule: the
+    plan-aware form of :func:`compressed_mix_with`, mixing with the plan
+    active at ``round_index`` (ignored by a CommPlan)."""
+    return compressed_mix_with(
+        lambda p: plan.select(round_index).mix(
+            p, plan.round_key(key, round_index), active=active, edge_live=edge_live
+        ),
+        params, residual, compression, update_mask=update_mask,
+    )
+
+
 def compressed_spread(
     plan,
     values: jax.Array,
@@ -375,14 +371,9 @@ def compressed_spread(
     codec — the invariant push-sum estimation needs survives compression
     untouched.
     """
-    if round_index is not None:
-        spread = lambda v: plan.spread(  # noqa: E731
-            v, round_index, key, active=active, edge_live=edge_live
-        )
-    else:
-        spread = lambda v: plan.spread(  # noqa: E731
-            v, key, active=active, edge_live=edge_live
-        )
+    spread = lambda v: plan.select(round_index).spread(  # noqa: E731
+        v, plan.round_key(key, round_index), active=active, edge_live=edge_live
+    )
     if not compression.active:
         return spread(values), residual
     v = jnp.asarray(values, jnp.float32)

@@ -310,6 +310,13 @@ class ShardedCommPlan:
     def n_edges(self) -> int:
         return self.base.n_edges
 
+    # a static plan: round selection is the identity, as on ``CommPlan``
+    def select(self, round_index=None) -> "ShardedCommPlan":
+        return self
+
+    def round_key(self, key: jax.Array | None, round_index=None) -> jax.Array | None:
+        return key
+
     def cross_shard_rows_per_round(self, op: str = "mix") -> int:
         """Total rows moved across devices per round, all collectives of the
         op included (static — the weak-scaling benchmark's traffic axis)."""

@@ -23,4 +23,12 @@ from .serve import (
     run_serve_trajectory,
     serve_summary,
 )
-from .trainer import DFLState, init_fl_state, make_eval_fn, make_round_fn, sigma_metrics, train_loop
+from .trainer import (
+    DFLState,
+    dfl_round,
+    init_fl_state,
+    make_eval_fn,
+    make_round_fn,
+    sigma_metrics,
+    train_loop,
+)

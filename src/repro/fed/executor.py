@@ -30,7 +30,10 @@ trajectory (DESIGN.md §11):
 
 ``round_fn`` is exactly the function ``make_round_fn`` builds — the executor
 re-uses it unchanged, which is what makes executor-vs-legacy parity
-bit-exact (same PRNG stream, same batch order, same round body).
+bit-exact (same PRNG stream, same batch order, same round body).  The
+node-sharded and elastic executors run the same round, ``trainer.dfl_round``,
+and own only what surrounds it: carry layout, metric reductions, membership
+masks, sketches.
 """
 from __future__ import annotations
 
@@ -50,9 +53,8 @@ from repro.checkpoint.io import restore_train_state, save_train_state
 from repro.core.commplan import CommPlan, PlanSchedule, compile_plan
 from repro.core.compress import (
     Compression,
-    compressed_mix,
     compressed_mix_with,
-    init_residuals,
+    mask_rows,
     seed_residual,
 )
 from repro.core.shardplan import ShardedCommPlan
@@ -68,7 +70,14 @@ from repro.obs.wirecost import (
 )
 
 from .batching import draw_batch, lane_dense
-from .trainer import DFLState, _local_steps, init_fl_state, make_round_fn, sigma_metrics
+from .trainer import (
+    DFLState,
+    _local_steps,
+    dfl_round,
+    init_fl_state,
+    make_round_fn,
+    sigma_metrics,
+)
 
 PyTree = Any
 
@@ -509,7 +518,6 @@ def run_sharded_trajectory(
     eval_fn=None,
     eval_batch=None,
     track_sigmas: bool = False,
-    reinit_opt: bool = True,
     b_local: int | None = None,
     compression: Compression | None = None,
 ) -> tuple[DFLState, dict[str, list]]:
@@ -521,10 +529,11 @@ def run_sharded_trajectory(
     operands, each shard scans its ``nps`` nodes' local steps, mixing runs
     through the plan's halo-exchange collectives (``local_mix``), and every
     per-round metric reduces with ``psum`` — no (n, d) array is ever
-    materialised on one device.  The round discipline (PRNG split, local
-    steps, mix, optimizer reinit) replicates ``make_round_fn`` exactly, so
-    final parameters are bit-identical to the single-device executor for
-    the same inputs (the property ``tests/test_sharded_plan.py`` pins).
+    materialised on one device.  Each round runs the one round
+    (``trainer.dfl_round``: PRNG split, local steps, mix, optimizer reinit)
+    with the halo-exchange mix as its operator, so final parameters are
+    bit-identical to the single-device executor for the same inputs (the
+    property ``tests/test_sharded_plan.py`` pins).
 
     Differences from ``run_trajectory``, both metric-only: scalar metrics
     reduce as ``psum(local sum)/n`` (a different summation order than one
@@ -552,7 +561,6 @@ def run_sharded_trajectory(
         mesh, ax, nps, n = plan.mesh, plan.axis, plan.nps, plan.n
         tables, tab_specs = plan.mix_operands()
         has_eval = eval_fn is not None
-        failures_active = plan.failures.active
         mask_np = cfg.eval_mask()
         xs_d, item_shape = lane_dense(xs_d)
         comp = compression if (compression is not None and compression.active) else None
@@ -576,32 +584,17 @@ def run_sharded_trajectory(
             return ap.astype(jnp.float32), (an_sum / d_total).astype(jnp.float32)
 
         def body(carry, per_round, xs_l, ys_l, t):
-            if comp is not None:
-                params, opt_state, rng, mirror = carry
-            else:
-                (params, opt_state, rng), mirror = carry, None
+            params, opt_state, rng, mirror = carry  # mirror: None uncompressed
             idx, do_eval = per_round  # idx: (nps, b, bs) local slice of the schedule
-            with jax.named_scope("dfl_round"):
-                rng, k_mix = jax.random.split(rng)
             with jax.named_scope("dfl_batch"):
-                bx, by = draw_batch(xs_l, ys_l, idx, item_shape)
-            with jax.named_scope("dfl_local"):
-                params, opt_state, losses = jax.vmap(partial(_local_steps, loss_fn, optimizer))(
-                    params, opt_state, (bx, by)
-                )
-            key = k_mix if failures_active else None
-            with jax.named_scope("dfl_mix"):
-                if comp is not None:
-                    # delta-form compressed halo mix: the mirror is shard-local (a
-                    # per-node-row transform), only h' rides the halo exchange
-                    params, mirror = compressed_mix_with(
-                        lambda q: plan.local_mix_any(q, key, t), params, mirror, comp
-                    )
-                else:
-                    params = plan.local_mix_any(params, key, t)
-            if reinit_opt:  # Algorithm 1 line 15
-                with jax.named_scope("dfl_reinit"):
-                    opt_state = jax.vmap(optimizer.init)(params)
+                batch = draw_batch(xs_l, ys_l, idx, item_shape)
+            # a compressed mix keeps the mirror shard-local (a per-node-row
+            # transform): only h' rides the halo exchange
+            params, opt_state, rng, mirror, losses, _ = dfl_round(
+                loss_fn, optimizer, lambda q, key: plan.local_mix_any(q, key, t),
+                params, opt_state, rng, batch,
+                failures=plan.failures, compression=comp, residual=mirror,
+            )
             with jax.named_scope("dfl_round"):
                 metrics = [jax.lax.psum(losses.sum(), ax).astype(jnp.float32) / n]
             if has_eval:
@@ -622,12 +615,7 @@ def run_sharded_trajectory(
                 with jax.named_scope("dfl_sigma"):
                     ap, an = sharded_sigmas(params)
                     metrics += [jnp.where(do_eval, ap, nan), jnp.where(do_eval, an, nan)]
-            new_carry = (
-                (params, opt_state, rng, mirror)
-                if comp is not None
-                else (params, opt_state, rng)
-            )
-            return new_carry, tuple(metrics)
+            return (params, opt_state, rng, mirror), tuple(metrics)
 
         def traj(carry, sched, mask, xs_l, ys_l, t):
             count("dfl.chunk_traces")
@@ -645,16 +633,12 @@ def run_sharded_trajectory(
         )
         data_spec = lambda a: P(ax, *([None] * (a.ndim - 1)))  # noqa: E731
         n_metrics = 1 + int(has_eval) + 2 * int(track_sigmas)
-        if comp is not None:
-            carry0 = (
-                state.params, state.opt_state, state.rng,
-                state.residual if state.residual is not None
-                else init_residuals(state.params),
-            )
-            cspecs = (pspecs, ospecs, P(), pspecs)
-        else:
-            carry0 = (state.params, state.opt_state, state.rng)
-            cspecs = (pspecs, ospecs, P())
+        state = seed_residual(state, comp)
+        carry0 = (
+            state.params, state.opt_state, state.rng,
+            state.residual if comp is not None else None,
+        )
+        cspecs = (pspecs, ospecs, P(), pspecs if comp is not None else P())
         f = jax.shard_map(
             traj,
             mesh=mesh,
@@ -672,10 +656,7 @@ def run_sharded_trajectory(
             carry, metrics = jax.jit(f)(
                 carry0, sched_d, jnp.asarray(mask_np), xs_d, ys_d, tables
             )
-        if comp is not None:
-            params, opt_state, rng, mirror = carry
-        else:
-            (params, opt_state, rng), mirror = carry, None
+        params, opt_state, rng, mirror = carry
         with span("dfl.assemble"):
             cols = [np.asarray(m) for m in metrics]
         # halo wire cost is a plan static (the cross-shard row set never changes
@@ -704,7 +685,6 @@ def _make_event_step(
     xs_d: jax.Array,
     ys_d: jax.Array,
     *,
-    reinit_opt: bool,
     comp: Compression | None,
     base_key: jax.Array,
 ):
@@ -760,16 +740,11 @@ def _make_event_step(
             params = plan.event_mix(params, e, k)
 
         # 3. pairwise optimizer-state reinit (Algorithm 1 line 15)
-        if reinit_opt:
-            pair_after = jax.tree_util.tree_map(lambda l: l[uv], params)
-            fresh = jax.vmap(optimizer.init)(pair_after)
-            kept = jax.tree_util.tree_map(lambda l: l[uv], opt_state)
-            fresh = jax.tree_util.tree_map(
-                lambda a, old: jnp.where(liv, a, old), fresh, kept
-            )
-            opt_state = jax.tree_util.tree_map(
-                lambda l, nl: l.at[uv].set(nl), opt_state, fresh
-            )
+        pair_after = jax.tree_util.tree_map(lambda l: l[uv], params)
+        fresh = jax.vmap(optimizer.init)(pair_after)
+        kept = jax.tree_util.tree_map(lambda l: l[uv], opt_state)
+        fresh = jax.tree_util.tree_map(lambda a, old: jnp.where(liv, a, old), fresh, kept)
+        opt_state = jax.tree_util.tree_map(lambda l, nl: l.at[uv].set(nl), opt_state, fresh)
 
         # 4. virtual clocks (staleness measured before the clocks move)
         stale = (t - clocks[uv]).mean()
@@ -794,7 +769,6 @@ def run_event_trajectory(
     n_bins: int = 20,
     eval_fn=None,
     eval_batch=None,
-    reinit_opt: bool = True,
     chunk_events: int = 0,
     checkpoint: CheckpointPolicy | None = None,
     resume_from: str | None = None,
@@ -881,7 +855,7 @@ def run_event_trajectory(
     rng, base_key = jax.random.split(state.rng)
     event_step = _make_event_step(
         loss_fn, optimizer, plan, sched_d, n_sched_rounds, xs_d, ys_d,
-        reinit_opt=reinit_opt, comp=comp, base_key=base_key,
+        comp=comp, base_key=base_key,
     )
 
     # per-bin accumulators riding the scan carry (repro.obs.BinSpec): sums /
@@ -901,10 +875,7 @@ def run_event_trajectory(
     horizon = float(stream.horizon)
 
     def body(carry, inp):
-        if comp is not None:
-            params, opt_state, counts, clocks, acc, mirror = carry
-        else:
-            (params, opt_state, counts, clocks, acc), mirror = carry, None
+        params, opt_state, counts, clocks, acc, mirror = carry  # mirror: None uncompressed
         i, e, t, b, do_ev = inp
         params, opt_state, counts, clocks, mirror, (liv, loss_mean, stale, delivered) = (
             event_step(params, opt_state, counts, clocks, mirror, i, e, t)
@@ -928,8 +899,7 @@ def run_event_trajectory(
                 lambda tb: tb,
                 acc["test_bin"],
             )
-        out = (params, opt_state, counts, clocks, acc)
-        return (out + (mirror,) if comp is not None else out), None
+        return (params, opt_state, counts, clocks, acc, mirror), None
 
     @jax.jit
     def drive_chunk(carry, inp):
@@ -943,9 +913,8 @@ def run_event_trajectory(
         jnp.zeros(n_nodes, jnp.int32),
         jnp.zeros(n_nodes, jnp.float32),
         bin_spec.init(),
+        state.residual if comp is not None else None,
     )
-    if comp is not None:
-        carry = carry + (state.residual,)
     inp_all = (
         jnp.arange(env, dtype=jnp.int32),
         jnp.asarray(stream.edges),
@@ -957,8 +926,7 @@ def run_event_trajectory(
     bounds = [(i0, min(i0 + size, env)) for i0 in range(0, env, size)]
     meta_id = {
         "kind": "event", "env": env, "n_bins": n_bins,
-        "chunk_events": size, "reinit_opt": bool(reinit_opt),
-        "compressed": comp is not None,
+        "chunk_events": size, "compressed": comp is not None,
     }
     skip = 0
     if resume_from is not None:
@@ -973,10 +941,7 @@ def run_event_trajectory(
             on_chunk(ci, i0, i1, carry[4])
         if checkpoint is not None:
             _save_chunk_ckpt(checkpoint, ci, ci == len(bounds) - 1, carry, [], meta_id)
-    if comp is not None:
-        params, opt_state, counts, clocks, acc, mirror = carry
-    else:
-        (params, opt_state, counts, clocks, acc), mirror = carry, None
+    params, opt_state, counts, clocks, acc, mirror = carry
     cnt_np = np.asarray(acc["cnt"])
     safe = np.maximum(cnt_np, 1.0)
     width = stream.horizon / n_bins
@@ -1025,7 +990,6 @@ def run_elastic_trajectory(
     eval_every: int = 0,
     eval_fn=None,
     eval_batch=None,
-    reinit_opt: bool = True,
     b_local: int | None = None,
     chunk_size: int = 0,
     init_one: Callable[[jax.Array, jax.Array], PyTree] | None = None,
@@ -1039,11 +1003,12 @@ def run_elastic_trajectory(
     """Elastic-membership fused trajectory: nodes join, leave, crash — the
     static-envelope rendering of DESIGN.md §16.
 
-    The scanned round body runs at the full n-node envelope every round;
-    a ``core.membership.MembershipSchedule`` lowers to per-round masks that
-    (a) freeze non-members' params/optimizer (their local phase computes
-    and is discarded — static shapes, no recompilation), and (b) thread
-    ``active=`` / ``edge_live=`` into the ``CommPlan`` operators, where the
+    Each round runs the one round (``trainer.dfl_round``) at the full
+    n-node envelope; a ``core.membership.MembershipSchedule`` lowers to
+    per-round masks that (a) are its ``live`` mask, freezing non-members'
+    params/optimizer (their local phase computes and is discarded — static
+    shapes, no recompilation), and (b) thread ``active=`` / ``edge_live=``
+    into the ``CommPlan`` operators of its mix, where the
     masked receive matrix renormalises members' rows over the live
     neighbourhood and turns non-members into identity rows.  A
     ``core.faults.FaultPlan`` ANDs its correlated outage masks into the
@@ -1090,9 +1055,7 @@ def run_elastic_trajectory(
             f"({n_rounds}, {n_nodes})"
         )
     if membership.trivial and trivial_faults:
-        round_fn = make_round_fn(
-            loss_fn, optimizer, plan, reinit_opt=reinit_opt, compression=compression
-        )
+        round_fn = make_round_fn(loss_fn, optimizer, plan, compression=compression)
         state, hist = run_trajectory(
             state, round_fn, xs, ys, schedule,
             n_rounds=n_rounds, eval_every=eval_every, eval_fn=eval_fn,
@@ -1107,18 +1070,15 @@ def run_elastic_trajectory(
     cfg = TrajectoryConfig(n_rounds, eval_every, False, chunk_size)
     count("dfl.calls")
     with span("dfl.trajectory", n_rounds=n_rounds, chunks=len(cfg.chunks())):
-        scheduled = isinstance(plan, PlanSchedule)
-        failures_active = plan.failures.active
         comp = compression if (compression is not None and compression.active) else None
         has_inits = bool(membership.inits.any())
         mask_np = cfg.eval_mask()
         sched_np = _as_round_schedule(schedule, n_rounds, b_local)
         xs_d, ys_d, eval_d = _device_data(xs, ys, eval_batch)
         xs_d, item_shape = lane_dense(xs_d)
-        n_edges = plan.n_edges_env if scheduled else plan.n_edges
         if trivial_faults:
             node_up = np.ones((n_rounds, n_nodes), bool)
-            edge_up = np.ones((n_rounds, max(n_edges, 1)), bool)
+            edge_up = np.ones((n_rounds, max(plan.n_edges, 1)), bool)
         else:
             node_up, edge_up = faults.node_up, faults.edge_up
 
@@ -1140,22 +1100,11 @@ def run_elastic_trajectory(
             channels.append(Channel("wire_messages", ints=True))
         rec = Recorder(MetricsSpec(tuple(channels)))
 
-        def per_node_where(cond, new, old):
-            return jax.tree_util.tree_map(
-                lambda a, b: jnp.where(cond.reshape((-1,) + (1,) * (a.ndim - 1)), a, b),
-                new, old,
-            )
-
         def body(carry, per_round):
-            if comp is not None:
-                params, opt_state, rng, sketches, mirror = carry
-            else:
-                (params, opt_state, rng, sketches), mirror = carry, None
+            params, opt_state, rng, sketches, mirror = carry  # mirror: None uncompressed
             idx, tr_m, gs_m, jn, ini, nup, eup, r, do_eval = per_round
             tr_eff = tr_m & nup
             gs_eff = gs_m & nup
-            rng, k_mix = jax.random.split(rng)
-            key = k_mix if failures_active else None
 
             # 1. joiners whose warmup just completed initialise uncoordinated,
             # with the size-only gain √n̂ from their own carried sketches
@@ -1166,67 +1115,63 @@ def run_elastic_trajectory(
                     sketches.sum(axis=1), jnp.float32(1e-30)), 1.0))
                 kr = jax.random.fold_in(k_init, r)
                 keys = jax.vmap(lambda i: jax.random.fold_in(kr, i))(jnp.arange(n_nodes))
-                p = per_node_where(ini, jax.vmap(init_one)(keys, gains), p)
-                o = per_node_where(ini, jax.vmap(optimizer.init)(p), o)
+                p = mask_rows(ini, jax.vmap(init_one)(keys, gains), p)
+                o = mask_rows(ini, jax.vmap(optimizer.init)(p), o)
                 return p, o
 
             if has_inits:
-                params, opt_state = jax.lax.cond(
-                    ini.any(), do_init, lambda po: po, (params, opt_state)
+                with jax.named_scope("dfl_round"):
+                    params, opt_state = jax.lax.cond(
+                        ini.any(), do_init, lambda po: po, (params, opt_state)
+                    )
+
+            # 2. the round at the full envelope: non-members are frozen, and
+            # only live trainers transmit, so only their mirrors advance
+            with jax.named_scope("dfl_batch"):
+                batch = draw_batch(xs_d, ys_d, idx, item_shape)
+
+            def mix(x, key):
+                return plan.select(r).mix(
+                    x, plan.round_key(key, r), active=tr_eff, edge_live=eup
                 )
 
-            # 2. local phase at the full envelope; non-members are frozen
-            bx, by = draw_batch(xs_d, ys_d, idx, item_shape)
-            new_p, new_o, losses = jax.vmap(partial(_local_steps, loss_fn, optimizer))(
-                params, opt_state, (bx, by)
+            params, opt_state, rng, mirror, losses, key = dfl_round(
+                loss_fn, optimizer, mix, params, opt_state, rng, batch,
+                failures=plan.failures, compression=comp, residual=mirror, live=tr_eff,
             )
-            params = per_node_where(tr_eff, new_p, params)
-            opt_state = per_node_where(tr_eff, new_o, opt_state)
 
             # 3. sketch transport: arrivals redraw, the gossip-active population
             # min-exchanges over the same per-round failure draws as the mix
-            fresh = jax.random.exponential(
-                jax.random.fold_in(k_fresh, r), (n_nodes, n_sketches)
-            )
-            sketches = jnp.where(jn[:, None], fresh, sketches)
-            if scheduled:
-                sketches = plan.spread_min(sketches, r, key, active=gs_eff, edge_live=eup)
-            else:
-                sketches = plan.spread_min(sketches, key, active=gs_eff, edge_live=eup)
-            if comp is not None:
-                # only live trainers transmitted → only their mirrors advance
-                params, mirror = compressed_mix(
-                    plan, params, mirror, key, compression=comp,
-                    round_index=r if scheduled else None,
-                    active=tr_eff, edge_live=eup, update_mask=tr_eff,
+            with jax.named_scope("dfl_round"):
+                fresh = jax.random.exponential(
+                    jax.random.fold_in(k_fresh, r), (n_nodes, n_sketches)
                 )
-            elif scheduled:
-                params = plan.mix(params, r, key, active=tr_eff, edge_live=eup)
-            else:
-                params = plan.mix(params, key, active=tr_eff, edge_live=eup)
-            if reinit_opt:  # Algorithm 1 line 15, members only
-                opt_state = per_node_where(
-                    tr_eff, jax.vmap(optimizer.init)(params), opt_state
+                sketches = jnp.where(jn[:, None], fresh, sketches)
+                sketches = plan.select(r).spread_min(
+                    sketches, plan.round_key(key, r), active=gs_eff, edge_live=eup
                 )
 
             # 4. metrics over the live training population
-            n_act = tr_eff.sum().astype(jnp.float32)
-            safe = jnp.maximum(n_act, 1.0)
-            values = {
-                "train_loss": ((losses * tr_eff).sum() / safe).astype(jnp.float32),
-                "n_active": n_act,
-            }
+            with jax.named_scope("dfl_round"):
+                n_act = tr_eff.sum().astype(jnp.float32)
+                safe = jnp.maximum(n_act, 1.0)
+                values = {
+                    "train_loss": ((losses * tr_eff).sum() / safe).astype(jnp.float32),
+                    "n_active": n_act,
+                }
             if wire_fn is not None:
-                values["wire_messages"] = wire_fn(key, r, active=tr_eff, edge_live=eup)
+                with jax.named_scope("dfl_wire"):
+                    values["wire_messages"] = wire_fn(key, r, active=tr_eff, edge_live=eup)
 
             def gated_metrics(p):
-                return {
-                    "test_loss": ((eval_fn(p, eval_d) * tr_eff).sum() / safe).astype(jnp.float32)
-                }
+                with jax.named_scope("dfl_eval"):
+                    per_node = eval_fn(p, eval_d)
+                with jax.named_scope("dfl_round"):
+                    loss = ((per_node * tr_eff).sum() / safe).astype(jnp.float32)
+                return {"test_loss": loss}
 
             out = rec.step(values, gate=do_eval, gated_fn=gated_metrics, operand=params)
-            new_carry = (params, opt_state, rng, sketches)
-            return (new_carry + (mirror,) if comp is not None else new_carry), out
+            return (params, opt_state, rng, sketches, mirror), out
 
         def chunk_inner(carry, sched_chunk, mask_chunk):
             count("dfl.chunk_traces")
@@ -1249,9 +1194,10 @@ def run_elastic_trajectory(
             jnp.arange(n_rounds, dtype=jnp.int32),
         )
         state = seed_residual(state, comp)
-        carry = (state.params, state.opt_state, state.rng, sketches0)
-        if comp is not None:
-            carry = carry + (state.residual,)
+        carry = (
+            state.params, state.opt_state, state.rng, sketches0,
+            state.residual if comp is not None else None,
+        )
         meta_id = {
             "kind": "elastic", "n_rounds": n_rounds, "eval_every": eval_every,
             "chunk_size": cfg.chunk_size, "n_sketches": n_sketches,
@@ -1281,10 +1227,7 @@ def run_elastic_trajectory(
             skip=skip, head_outs=head_outs, checkpoint=checkpoint, ckpt_meta=meta_id,
             on_chunk=hook,
         )
-        if comp is not None:
-            params, opt_state, rng, sketches, mirror = carry
-        else:
-            (params, opt_state, rng, sketches), mirror = carry, None
+        params, opt_state, rng, sketches, mirror = carry
         hist = _finish_wire(rec.assemble(mask_np, cols), None, row_bytes)
         final = DFLState(
             params=params, opt_state=opt_state,

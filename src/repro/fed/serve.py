@@ -239,7 +239,6 @@ def run_serve_trajectory(
     n_bins: int = 20,
     eval_fn=None,
     eval_batch=None,
-    reinit_opt: bool = True,
     service_time: float = 0.05,
     hop_latency: float = 0.02,
     serve_fn: Callable[[PyTree, jax.Array], jax.Array] | None = None,
@@ -323,7 +322,6 @@ def run_serve_trajectory(
         n_sched_rounds,
         xs_d,
         ys_d,
-        reinit_opt=reinit_opt,
         comp=None,
         base_key=base_key,
     )

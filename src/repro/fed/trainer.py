@@ -8,6 +8,9 @@ communication round =
     2. DecAvg aggregation over the graph           (line 14, Eq. 2)
     3. optimizer-state re-initialisation           (line 15)
 
+``dfl_round`` is that round, written once: the fused single-chip,
+node-sharded and elastic executors all run it, and own only what
+surrounds it (carry layout, metric reductions, membership masks).
 The round function is model-agnostic: it takes any per-node
 ``loss_fn(params, batch) -> scalar`` and vmaps it over the node axis.  Under
 ``jax.jit`` with the node axis sharded over the mesh "data" axis this is the
@@ -27,14 +30,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.commplan import CommPlan, FailureModel, PlanSchedule, compile_plan
-from repro.core.compress import Compression, compressed_mix, init_residuals
+from repro.core.compress import Compression, compressed_mix_with, init_residuals, mask_rows
 from repro.core.topology import Graph
 from repro.optim import Optimizer
 
 PyTree = Any
 LossFn = Callable[[PyTree, Any], jax.Array]
 
-__all__ = ["DFLState", "init_fl_state", "make_round_fn", "make_eval_fn", "sigma_metrics", "train_loop"]
+__all__ = [
+    "DFLState",
+    "dfl_round",
+    "init_fl_state",
+    "make_round_fn",
+    "make_eval_fn",
+    "sigma_metrics",
+    "train_loop",
+]
 
 
 @jax.tree_util.register_pytree_node_class
@@ -103,6 +114,70 @@ def _local_steps(
     return params, opt_state, losses.mean()
 
 
+def dfl_round(
+    loss_fn: LossFn,
+    optimizer: Optimizer,
+    mix: Callable[[PyTree, jax.Array | None], PyTree] | None,
+    params: PyTree,
+    opt_state: PyTree,
+    rng: jax.Array,
+    batches: Any,
+    *,
+    failures: FailureModel,
+    compression: Compression | None = None,
+    residual: PyTree | None = None,
+    live: jax.Array | None = None,
+) -> tuple[PyTree, PyTree, jax.Array, PyTree | None, jax.Array, jax.Array | None]:
+    """One round of Algorithm 1 over a node-stacked ensemble — the body that
+    ``make_round_fn``, ``run_sharded_trajectory`` and
+    ``run_elastic_trajectory`` all run:
+
+    1. split the round's mix key off ``rng``                  (``dfl_round``)
+    2. b local minibatch steps per node, lines 8–10           (``dfl_local``)
+    3. ``mix(x, key)``, the caller's DecAvg operator, line 14 (``dfl_mix``):
+       ``key`` is the round's key under an active ``failures`` model, else
+       None.  An active ``compression`` runs the error-feedback delta form
+       around it (``compressed_mix_with``), carrying ``residual``.
+    4. optimizer re-initialisation, line 15                   (``dfl_reinit``)
+
+    ``mix=None`` trains locally only: no mix, no re-initialisation.
+    ``live`` ((n,) bool) freezes the nodes outside it: they keep their
+    params, optimizer state and mirror (their local steps are computed and
+    discarded — static shapes).  The caller's ``mix`` should treat them as
+    identity rows (``active=``).
+
+    Returns ``(params, opt_state, rng, residual, losses, key)``: ``losses``
+    per node, and ``key`` the key the mix consumed, so the caller's other
+    draws of the round (sketches, wire counts) ride the same failures.
+    """
+    with jax.named_scope("dfl_round"):
+        rng, k_mix = jax.random.split(rng)
+
+    with jax.named_scope("dfl_local"):
+        new_p, new_o, losses = jax.vmap(partial(_local_steps, loss_fn, optimizer))(
+            params, opt_state, batches
+        )
+        if live is not None:
+            new_p = mask_rows(live, new_p, params)
+            new_o = mask_rows(live, new_o, opt_state)
+    params, opt_state = new_p, new_o
+
+    key = k_mix if failures.active else None
+    if mix is not None:
+        with jax.named_scope("dfl_mix"):
+            if compression is not None and compression.active:
+                params, residual = compressed_mix_with(
+                    lambda q: mix(q, key), params, residual, compression,
+                    update_mask=live,
+                )
+            else:
+                params = mix(params, key)
+        with jax.named_scope("dfl_reinit"):
+            fresh = jax.vmap(optimizer.init)(params)
+            opt_state = fresh if live is None else mask_rows(live, fresh, opt_state)
+    return params, opt_state, rng, residual, losses, key
+
+
 def make_round_fn(
     loss_fn: LossFn,
     optimizer: Optimizer,
@@ -110,7 +185,6 @@ def make_round_fn(
     data_sizes: np.ndarray | None = None,
     link_p: float = 1.0,
     node_p: float = 1.0,
-    reinit_opt: bool = True,
     aggregate: bool = True,
     compression: Compression | None = None,
 ):
@@ -126,7 +200,8 @@ def make_round_fn(
 
     Returns ``round_fn(state, node_batches) -> (state, metrics)`` where
     ``node_batches`` leaves are (n_nodes, b, batch, ...): b local minibatches
-    per node per round (Appendix A: b = 8).
+    per node per round (Appendix A: b = 8).  The round is :func:`dfl_round`;
+    ``aggregate=False`` trains locally only.
 
     ``compression`` (an active ``core.compress.Compression``) switches the
     aggregation to the error-feedback delta form over the same plan
@@ -144,37 +219,22 @@ def make_round_fn(
         plan = plan.with_options(
             data_sizes=data_sizes, failures=failures if failures.active else None
         )
-    scheduled = isinstance(plan, PlanSchedule)
-    comp = compression if (compression is not None and compression.active) else None
+    comp = compression if (aggregate and compression is not None and compression.active) else None
 
     def round_fn(state: DFLState, node_batches: Any) -> tuple[DFLState, dict]:
-        with jax.named_scope("dfl_round"):
-            rng, k_mix = jax.random.split(state.rng)
+        r = state.round
 
-        with jax.named_scope("dfl_local"):
-            params, opt_state, losses = jax.vmap(
-                partial(_local_steps, loss_fn, optimizer)
-            )(state.params, state.opt_state, node_batches)
+        def mix(x, key):
+            return plan.select(r).mix(x, plan.round_key(key, r))
 
         residual = state.residual
-        if aggregate:
-            key = k_mix if plan.failures.active else None
-            with jax.named_scope("dfl_mix"):
-                if comp is not None:
-                    if residual is None:  # legacy train_loop path (no seeding)
-                        residual = init_residuals(params)
-                    params, residual = compressed_mix(
-                        plan, params, residual, key, compression=comp,
-                        round_index=state.round if scheduled else None,
-                    )
-                elif scheduled:
-                    params = plan.mix(params, state.round, key)
-                else:
-                    params = plan.mix(params, key=key)
-            if reinit_opt:  # Algorithm 1 line 15
-                with jax.named_scope("dfl_reinit"):
-                    opt_state = jax.vmap(optimizer.init)(params)
-
+        if comp is not None and residual is None:  # legacy train_loop path (no seeding)
+            residual = init_residuals(state.params)
+        params, opt_state, rng, residual, losses, _ = dfl_round(
+            loss_fn, optimizer, mix if aggregate else None,
+            state.params, state.opt_state, state.rng, node_batches,
+            failures=plan.failures, compression=comp, residual=residual,
+        )
         with jax.named_scope("dfl_round"):
             new_state = DFLState(
                 params=params, opt_state=opt_state, round=state.round + 1, rng=rng,
@@ -187,7 +247,7 @@ def make_round_fn(
     # accountant reads it to count exactly the edges this round_fn mixes over;
     # the compression config rides along for codec-aware byte accounting
     round_fn.plan = plan if aggregate else None
-    round_fn.compression = comp if aggregate else None
+    round_fn.compression = comp
     return round_fn
 
 

@@ -140,12 +140,9 @@ def _scan_rounds(
     a whole budget grid, ``fed.executor.run_warmup_sweep``)."""
     if plan.failures.active and key is None:
         raise ValueError("failure model active: gossip needs a PRNG key")
-    scheduled = isinstance(plan, PlanSchedule)
-
     def body(x, r):
         k = None if key is None else jax.random.fold_in(key, r)
-        f = getattr(plan, op)
-        x1 = f(x, r, k) if scheduled else f(x, k)
+        x1 = getattr(plan.select(r), op)(x, plan.round_key(k, r))
         if active is not None:
             x1 = jnp.where(r - round_offset < active, x1, x)
         return x1, (x1 if trace else None)

@@ -68,7 +68,7 @@ def param_row_bytes(
 
 
 def _event_plan(plan: CommPlan | PlanSchedule) -> CommPlan | None:
-    probe = plan.plans[0] if isinstance(plan, PlanSchedule) else plan
+    probe = plan.plans[0]
     return probe if probe.event_uv is not None else None
 
 
@@ -81,11 +81,10 @@ def static_wire_messages(plan: CommPlan | PlanSchedule, n_rounds: int) -> np.nda
     """
     if _event_plan(plan) is None:
         return None
-    if isinstance(plan, PlanSchedule):
-        idx = np.asarray(plan.plan_index(np.arange(n_rounds)))
-        per_plan = np.array([2 * p.n_edges for p in plan.plans], dtype=np.int64)
-        return per_plan[idx]
-    return np.full(n_rounds, 2 * plan.n_edges, dtype=np.int64)
+    per_plan = np.array([2 * p.n_edges for p in plan.plans], dtype=np.int64)
+    if len(per_plan) == 1:
+        return np.full(n_rounds, per_plan[0])
+    return per_plan[np.asarray(plan.plan_index(np.arange(n_rounds)))]
 
 
 def make_wire_fn(
@@ -104,12 +103,11 @@ def make_wire_fn(
     """
     if _event_plan(plan) is None:
         return None
-    scheduled = isinstance(plan, PlanSchedule)
-
     def wire(key, round_index, active=None, edge_live=None) -> jax.Array:
-        view = plan.select(round_index) if scheduled else plan
-        k = plan.round_key(key, round_index) if scheduled else key
-        edge_keep, node_act = view._round_masks_ext(k, active, edge_live)
+        view = plan.select(round_index)
+        edge_keep, node_act = view._round_masks_ext(
+            plan.round_key(key, round_index), active, edge_live
+        )
         uv = view.event_uv
         # schedule envelopes pad event rows with exactly-zero weights (and a
         # 1-row pad on edgeless graphs) — real edges always weigh > 0
